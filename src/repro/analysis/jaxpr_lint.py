@@ -143,7 +143,7 @@ def lint_program(program: BucketedProgram, n_tt: int,
     for bucket in program.buckets:
         loc = f"bucket {bucket.label}"
         specs = program.abstract_args(bucket, n_tt, eff)
-        fn = body_builder(bucket, program.use_pallas)
+        fn = body_builder(bucket, program.use_pallas, program.role)
         out.extend(lint_traced(fn, specs, location=loc))
         keyed.append(((bucket.static, bucket.cap),
                       program.cache_key(bucket, specs), loc))

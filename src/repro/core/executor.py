@@ -29,6 +29,7 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
+from repro import trace
 from repro.core.state import State
 from repro.query import engine as E
 from repro.query import ref_engine as R
@@ -267,9 +268,10 @@ class QueryExecutor:
         """Answer every member rewriting in one fused device call
         (cached; overflow recovered adaptively)."""
         if self._results is None:
-            roots = self.workload.run(self.tt, self.device_views)
-            self._results = {name: E.to_numpy(rel)
-                             for name, rel in roots.items()}
+            with trace.span("rdfviews.query.fused_run"):
+                roots = self.workload.run(self.tt, self.device_views)
+                self._results = {name: E.to_numpy(rel)
+                                 for name, rel in roots.items()}
         return self._results
 
     def answer(self, name: str) -> np.ndarray:

@@ -41,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.quality import MaintenanceCostModel
 from repro.core.queries import Const
 from repro.errors import InvariantViolation
@@ -189,7 +190,8 @@ class ViewMaintainer:
             self._delta_exec = WorkloadExecutor(
                 self.plans.dag, executor.store.stats,
                 self.plans.view_infos(self.cfg.expected_batch),
-                safety=self.cfg.safety, use_pallas=executor._use_pallas)
+                safety=self.cfg.safety, use_pallas=executor._use_pallas,
+                role="delta")
         self._repack_extents()
         # per-view extent length at the last statistics recount (the
         # cost model's RelInfo refresh is throttled to material drift)
@@ -263,7 +265,8 @@ class ViewMaintainer:
         keys_snap, rows_snap = dict(self._ext_keys), dict(self._info_rows)
         cap_snap = self.tt_cap
         try:
-            return self._apply(delta)
+            with trace.span("rdfviews.maint.pass"):
+                return self._apply(delta)
         except Exception:
             ex.restore(ex_snap)
             self._ext_keys, self._info_rows = keys_snap, rows_snap
@@ -275,22 +278,27 @@ class ViewMaintainer:
         ex = self.executor
         t0 = time.perf_counter()
         store = ex.store
-        eff_ins, eff_del = effective_delta(store, delta.inserts, delta.deletes)
+        with trace.span("rdfviews.maint.store"):
+            eff_ins, eff_del = effective_delta(store, delta.inserts,
+                                               delta.deletes)
+            new_store = store.apply_delta(delta.inserts, delta.deletes)
         report = MaintenanceReport(
             n_inserts=len(delta.inserts), n_deletes=len(delta.deletes),
             eff_inserts=len(eff_ins), eff_deletes=len(eff_del),
             oracle_views=len(self.plans.oracle_vids))
-        new_store = store.apply_delta(delta.inserts, delta.deletes)
 
         oracle_vids = self.plans.oracle_vids
         if len(eff_del):
-            self._delete_pass(eff_del, oracle_vids, report)
+            with trace.span("rdfviews.maint.delete"):
+                self._delete_pass(eff_del, oracle_vids, report)
 
-        self._upload_tt(new_store, report)
+        with trace.span("rdfviews.maint.tt_upload"):
+            self._upload_tt(new_store, report)
         ex.note_maintenance(new_store)
 
         if len(eff_ins):
-            self._insert_pass(eff_ins, oracle_vids, report)
+            with trace.span("rdfviews.maint.insert"):
+                self._insert_pass(eff_ins, oracle_vids, report)
         if oracle_vids and (len(eff_ins) or len(eff_del)):
             self._oracle_pass(store, eff_ins, eff_del, oracle_vids, report)
             self.oracle_batches += 1
@@ -365,10 +373,11 @@ class ViewMaintainer:
     # -- insertion -----------------------------------------------------
     def _insert_pass(self, eff_ins: np.ndarray, skip: set[int],
                      report: MaintenanceReport) -> None:
-        if self.engine == "host":
-            per_vid = self._insert_candidates_host(eff_ins)
-        else:
-            per_vid = self._insert_candidates_device(eff_ins)
+        with trace.span("rdfviews.maint.delta_program"):
+            if self.engine == "host":
+                per_vid = self._insert_candidates_host(eff_ins)
+            else:
+                per_vid = self._insert_candidates_device(eff_ins)
         ex = self.executor
         for vid, parts in per_vid.items():
             cand = parts[0] if len(parts) == 1 else np.concatenate(parts)
@@ -385,7 +394,8 @@ class ViewMaintainer:
             # copy-on-write (see _delete_pass): replace, never mutate
             self._ext_keys[vid] = seen | fresh_keys
             fresh = cand[np.asarray(fresh_at)]
-            self._append_rows(vid, fresh, report)
+            with trace.span("rdfviews.maint.append"):
+                self._append_rows(vid, fresh, report)
             report.appended[vid] = len(fresh)
 
     def _insert_candidates_device(self, eff_ins: np.ndarray

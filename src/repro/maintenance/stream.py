@@ -13,6 +13,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from repro import trace
+
 
 def _as_triples(arr) -> np.ndarray:
     return (np.zeros((0, 3), np.int32) if arr is None
@@ -41,7 +43,12 @@ class UpdateStream:
     """FIFO of pending update batches with backlog accounting."""
 
     def __init__(self) -> None:
-        self._queue: deque[Delta] = deque()
+        # (delta, stamps): the (sequence number, push time) of each
+        # submitted batch the delta holds that was pushed while tracing
+        # was on
+        self._queue: deque[tuple[Delta, tuple]] = deque()
+        # the stamps handed to the running pass, and when it started
+        self._in_pass: tuple = ((), None)
         self.total_pushed = 0      # triples ever submitted
         self.total_batches = 0
         self.total_applied = 0     # triples handed to the maintainer
@@ -49,14 +56,26 @@ class UpdateStream:
     def push(self, delta: Delta) -> None:
         if delta.size == 0:
             return
-        self._queue.append(delta)
+        t = trace.now_ns()
+        self._queue.append(
+            (delta, () if t is None else ((self.total_batches, t),)))
         self.total_pushed += delta.size
         self.total_batches += 1
+
+    def applied(self) -> None:
+        """The pass that took the last `pop`/`coalesce` succeeded: record
+        each stamped batch's wait, from its push to that pass's start."""
+        stamps, start = self._in_pass
+        self._in_pass = ((), None)
+        if start is not None:
+            for seq, t in stamps:
+                trace.record("rdfviews.maint.queued", t, start, seq=seq)
 
     def pop(self) -> Delta | None:
         if not self._queue:
             return None
-        delta = self._queue.popleft()
+        delta, stamps = self._queue.popleft()
+        self._in_pass = (stamps, trace.now_ns())
         self.total_applied += delta.size
         return delta
 
@@ -65,9 +84,11 @@ class UpdateStream:
         to the head of the queue (sequential semantics preserved) and is
         un-counted from `total_applied` so backlog accounting stays
         truthful while the serving layer reports staleness."""
+        stamps, _ = self._in_pass     # they keep waiting from their push
+        self._in_pass = ((), None)
         if delta.size == 0:
             return
-        self._queue.appendleft(delta)
+        self._queue.appendleft((delta, stamps))
         self.total_applied -= delta.size
 
     def coalesce(self) -> Delta | None:
@@ -81,7 +102,9 @@ class UpdateStream:
 
         if not self._queue:
             return None
-        batches = list(self._queue)
+        batches = [b for b, _ in self._queue]
+        self._in_pass = (tuple(st for _, sts in self._queue for st in sts),
+                         trace.now_ns())
         self._queue.clear()
         parts, ops = [], []
         for b in batches:  # within a batch the insert outranks the delete
@@ -108,7 +131,7 @@ class UpdateStream:
 
     @property
     def pending_triples(self) -> int:
-        return sum(b.size for b in self._queue)
+        return sum(b.size for b, _ in self._queue)
 
     def __len__(self) -> int:
         return len(self._queue)

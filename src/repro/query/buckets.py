@@ -44,11 +44,16 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import trace
 from repro.query import cost as cost_mod
 from repro.query import engine as E
 from repro.query.dag import WorkloadDAG
 
 CAP_CEIL = 1 << 22
+
+# the program span of one bucket dispatch, by operator kind
+_BUCKET_SPANS = {k: f"rdfviews.query.bucket.{k}"
+                 for k in ("scan", "filter", "join", "project")}
 
 # Default LRU bound of the process-global compile cache.  A long-lived
 # TuningSession.retune() loop churns through bucket shapes; without a
@@ -295,20 +300,35 @@ def _project_body(static):
     return fn
 
 
-def body_builder(bucket: Bucket, use_pallas: bool = False):
+def body_builder(bucket: Bucket, use_pallas: bool = False,
+                 role: str = "workload"):
     """The traced body function for one bucket, built from its static
     signature alone — the same builder `_run_bucket` compiles through
     the cache, exposed so the jaxpr lint (`repro.analysis.jaxpr_lint`)
-    can trace every body abstractly without executing anything."""
-    if bucket.kind == "scan":
-        return _scan_body(bucket.static, bucket.cap)
-    if bucket.kind == "filter":
-        return _filter_body(bucket.static)
-    if bucket.kind == "join":
-        return _join_body(bucket.static, bucket.cap, use_pallas)
-    if bucket.kind == "project":
-        return _project_body(bucket.static)
-    raise TypeError(bucket.kind)
+    can trace every body abstractly without executing anything.
+
+    The body is named `<role>_<kind>` (`role` is the program's: see
+    `BucketedProgram`), so a device trace shows it as
+    `jit_<role>_<kind>`, and its operator runs under
+    `jax.named_scope(<kind>)`."""
+    kind = bucket.kind
+    if kind == "scan":
+        op = _scan_body(bucket.static, bucket.cap)
+    elif kind == "filter":
+        op = _filter_body(bucket.static)
+    elif kind == "join":
+        op = _join_body(bucket.static, bucket.cap, use_pallas)
+    elif kind == "project":
+        op = _project_body(bucket.static)
+    else:
+        raise TypeError(kind)
+
+    def fn(*args):
+        with jax.named_scope(kind):
+            return op(*args)
+
+    fn.__name__ = fn.__qualname__ = f"{role}_{kind}"
+    return fn
 
 
 def _specs_of(args) -> tuple:
@@ -397,15 +417,22 @@ class BucketedProgram:
     capacity class; only those buckets' bodies (and consumers whose
     operand shapes changed) recompile on the next execute — everything
     else hits the persistent cache.
+
+    `role` names what the program is for ("workload": the serving
+    program; "delta": the maintainer's insert-delta program;
+    "materialize": device materialization).  It names every bucket body
+    (`body_builder`) and is part of each compile-cache key, so a body
+    compiled under one name is never served under another.
     """
 
     def __init__(self, dag: WorkloadDAG, stats, view_infos, *,
                  safety: float = 4.0, use_pallas: bool = False,
                  cap_planner=None, ests=None,
-                 carry_caps: dict | None = None):
+                 carry_caps: dict | None = None, role: str = "workload"):
         self.dag = dag
         self.stats = stats
         self.use_pallas = use_pallas
+        self.role = role
         if ests is None:
             ests = cost_mod.estimate_dag(dag, stats, view_infos)
         self.ests = ests
@@ -521,7 +548,7 @@ class BucketedProgram:
     # ------------------------------------------------------------------
     def _run_bucket(self, bucket: Bucket, tt, res, eff_cap):
         dag = self.dag
-        build = lambda: body_builder(bucket, self.use_pallas)
+        build = lambda: body_builder(bucket, self.use_pallas, self.role)
         if bucket.kind == "scan":
             _, idx_name = bucket.static[0], bucket.static[1]
             args = (tt[idx_name], bucket.pvals, bucket.rvals)
@@ -586,7 +613,10 @@ class BucketedProgram:
                 res[node.id] = (None, rel)
                 eff_cap[node.id] = rel.cap
                 view_nids.append(node.id)
-        outs = [self._run_bucket(b, tt, res, eff_cap) for b in self.buckets]
+        outs = []
+        for b in self.buckets:
+            with trace.span(_BUCKET_SPANS[b.kind]):
+                outs.append(self._run_bucket(b, tt, res, eff_cap))
 
         # host-side overflow attribution: one transfer for all flags
         flat = jax.device_get(
@@ -686,7 +716,7 @@ class BucketedProgram:
         """The persistent-cache key `_run_bucket` would use for this
         bucket with operands of `specs` shapes (lint checks hashability
         and cross-bucket collision-freedom of exactly these keys)."""
-        return (bucket.static, bucket.cap, self.use_pallas,
+        return (self.role, bucket.static, bucket.cap, self.use_pallas,
                 _shape_key(specs))
 
     # ------------------------------------------------------------------

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import trace
 from repro.core.queries import Const, Var
 from repro.query import cost as cost_mod
 from repro.query.plan import EquiJoin, Filter, Plan, Project, TTScan, ViewRef
@@ -55,8 +56,9 @@ def make_prel(rows: np.ndarray, cap: int) -> PRel:
 
 
 def to_numpy(rel: PRel) -> np.ndarray:
-    n = int(rel.n)
-    return np.asarray(rel.data[:n])
+    with trace.span("rdfviews.query.to_numpy"):
+        n = int(rel.n)
+        return np.asarray(rel.data[:n])
 
 
 def _valid_mask(rel: PRel) -> jax.Array:

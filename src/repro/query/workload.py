@@ -180,7 +180,7 @@ class WorkloadExecutor:
                  cap_planner: Callable[[object, float], int] | None = None,
                  mode: str = "bucketed",
                  carry_caps: dict | None = None,
-                 fault_hook=None):
+                 fault_hook=None, role: str = "workload"):
         if mode not in ("bucketed", "unrolled"):
             raise ValueError(f"unknown workload mode {mode!r}")
         # fault_hook: duck-typed chaos injector (`.fire(site)` raising an
@@ -188,6 +188,8 @@ class WorkloadExecutor:
         # here: "compile" on program (re)construction, "device_call" and
         # "capacity_overflow" on each run.
         self.fault_hook = fault_hook
+        # names the bucketed program's bodies (BucketedProgram's `role`)
+        self.role = role
         self.dag = dag
         self.stats = stats
         self.view_infos = view_infos
@@ -228,6 +230,7 @@ class WorkloadExecutor:
                               caps=self.caps, cap_planner=self.cap_planner,
                               ests=self._ensure_ests())
         self.caps = fn.caps
+        fn.__name__ = fn.__qualname__ = f"{self.role}_unrolled"
         self._jit = jax.jit(fn)
         self.compiles += 1
 
@@ -237,7 +240,8 @@ class WorkloadExecutor:
             self._prog = BucketedProgram(
                 self.dag, self.stats, self.view_infos, safety=self.safety,
                 use_pallas=self.use_pallas, cap_planner=self.cap_planner,
-                ests=self._ensure_ests(), carry_caps=self.carry_caps)
+                ests=self._ensure_ests(), carry_caps=self.carry_caps,
+                role=self.role)
             self.caps = self._prog.caps
             self.compiles += 1
         return self._prog

@@ -55,6 +55,7 @@ from __future__ import annotations
 import time
 from dataclasses import dataclass, field
 
+from repro import trace
 from repro.core.executor import QueryExecutor
 from repro.distributed.fault import RetryPolicy, ServingSupervisor
 from repro.errors import ServiceUnavailable
@@ -263,6 +264,7 @@ class QueryServer:
                 self.stats.backlog_batches = self.stream.pending_batches
                 self.stats.backlog_triples = self.stream.pending_triples
                 raise
+            self.stream.applied()
             reports.append(report)
             self.stats.refreshes += 1
             self.stats.updates_applied += (report.eff_inserts
@@ -309,11 +311,12 @@ class QueryServer:
         corrupt — the fused and per-query tiers (which read the device
         buffers and, for oracle fallbacks, the mirrors) must not serve
         until re-materialization repairs it."""
-        for vid, dev in self.executor.device_views.items():
-            rel = self.executor.extents.get(vid)
-            if rel is None or len(rel.rows) != int(dev.n):
-                return False
-        return True
+        with trace.span("rdfviews.serve.integrity"):
+            for vid, dev in self.executor.device_views.items():
+                rel = self.executor.extents.get(vid)
+                if rel is None or len(rel.rows) != int(dev.n):
+                    return False
+            return True
 
     def _note_fault(self, kind: str, exc) -> None:
         self.stats.faults.append(f"{kind}: {exc}")
@@ -345,8 +348,9 @@ class QueryServer:
                 try:
                     t0 = time.perf_counter()
                     self.executor.answer_workload()  # one device call
-                    answers = {n: self.executor.answer_group(n)
-                               for n in known}
+                    with trace.span("rdfviews.serve.assemble"):
+                        answers = {n: self.executor.answer_group(n)
+                                   for n in known}
                     elapsed = time.perf_counter() - t0
                     if (pol.call_timeout_seconds is not None
                             and elapsed > pol.call_timeout_seconds):
@@ -411,6 +415,11 @@ class QueryServer:
         serving).  Raises `ServiceUnavailable` — and goes DOWN — only
         when every tier and the last-known-good cache fail.
         """
+        with trace.span("rdfviews.serve.answer_batch", new_batch=True):
+            return self._answer_batch(names)
+
+    def _answer_batch(self, names: list[str]
+                      ) -> list[set[tuple[int, ...]] | None]:
         self.supervisor.begin_batch()
         stale = False
         try:

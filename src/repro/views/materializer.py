@@ -168,7 +168,8 @@ def materialize_state_device(state: State, store: TripleStore,
     if plans:
         dag = build_dag(plans)
         wl = WorkloadExecutor(dag, store.stats, {}, safety=safety,
-                              use_pallas=use_pallas, max_retries=max_retries)
+                              use_pallas=use_pallas, max_retries=max_retries,
+                              role="materialize")
         roots = wl.run(E.tt_device_indexes(store), {})
     for vid, view in state.views.items():
         if vid in oracle_vids:

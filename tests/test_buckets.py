@@ -1,11 +1,12 @@
 """Shape-bucket planning, the persistent compile cache, and bucketed
 execution mechanics (query/buckets.py)."""
+import jax
 import pytest
 
 from repro.core.queries import Atom, CQ, Const, Var
 from repro.query import engine as E
 from repro.query import ref_engine as R
-from repro.query.buckets import (CAP_CEIL, BucketedProgram,
+from repro.query.buckets import (CAP_CEIL, BucketedProgram, body_builder,
                                  clear_compile_cache, compile_cache,
                                  node_waves)
 from repro.query.dag import build_dag
@@ -124,6 +125,45 @@ def test_compile_cache_persists_across_programs(uni):
     for q in qs:
         got = {tuple(r) for r in E.to_numpy(roots2[q.name]).tolist()}
         assert got == R.evaluate_cq(q, uni.store).as_set(), q.name
+
+
+@pytest.mark.parametrize("role", ["workload", "delta"])
+def test_bucket_bodies_are_named_by_role_and_kind(uni, role):
+    """Every lowered body is `jit_<role>_<kind>`, so a device trace
+    tells serving from maintenance and joins from scans."""
+    prog = BucketedProgram(_dag(uni, _queries(uni)), uni.store.stats, {},
+                           cap_planner=lambda node, rows: 256, role=role)
+    eff = prog.static_eff_caps()
+    kinds = set()
+    for b in prog.buckets:
+        specs = prog.abstract_args(b, len(uni.store), eff)
+        fn = body_builder(b, prog.use_pallas, prog.role)
+        assert fn.__name__ == f"{role}_{b.kind}"
+        text = jax.jit(fn).lower(*specs).as_text()
+        assert f"jit_{role}_{b.kind}" in text
+        kinds.add(b.kind)
+    assert {"scan", "join"} <= kinds
+
+
+def test_roles_get_distinct_cache_keys(uni):
+    """The same bucket under two roles is two cache entries: a body
+    compiled under one name is never served under the other."""
+    dag = _dag(uni, _queries(uni))
+    planner = lambda node, rows: 256
+    work = BucketedProgram(dag, uni.store.stats, {}, cap_planner=planner)
+    delta = BucketedProgram(dag, uni.store.stats, {}, cap_planner=planner,
+                            role="delta")
+    assert work.role == "workload"
+    eff = work.static_eff_caps()
+    for bw, bd in zip(work.buckets, delta.buckets):
+        specs = work.abstract_args(bw, len(uni.store), eff)
+        assert work.cache_key(bw, specs) != delta.cache_key(bd, specs)
+    clear_compile_cache()
+    tt = E.tt_device_indexes(uni.store)
+    work.execute(tt, {})
+    delta.execute(tt, {})
+    assert delta.cache_hits == 0 and delta.cache_misses == delta.n_buckets
+    assert compile_cache().stats()["entries"] == 2 * work.n_buckets
 
 
 # ----------------------------------------------------------------------

@@ -5,8 +5,11 @@ Per batch (one device maintenance pass, shapes constant in steady state):
   1. net the batch against the store (effective inserts/deletes);
   2. deletion pass — wizard views are full projections, so a row dies
      iff one of its instantiated atom triples is deleted: a host-side
-     membership mask over the extent mirror, applied on device by the
-     stable-partition `compact` (per capacity class, one compiled fn);
+     membership mask compacts the extent mirror, and the device buffer
+     is brought in line by uploading that mirror, padded, into its
+     capacity class (at once on the device engine, whose insert pass
+     appends into the buffer next; deferred to the end of the batch on
+     the host engine);
   3. upload TT' padded to a capacity class (`tt_device_indexes_padded`)
      — scan operand shapes never change while the store grows within
      the class;
@@ -28,9 +31,9 @@ Per batch (one device maintenance pass, shapes constant in steady state):
   6. the drift detector observes the batch and may recommend a retune.
 
 The executor's host extent mirrors and device buffers stay row-aligned
-throughout (appends concatenate, deletes stable-partition on both
-sides) — that alignment is what lets the deletion mask be computed on
-the host and applied on the device without a gather-back.
+throughout (appends concatenate on both sides, deletes re-upload the
+compacted mirror) — that alignment is what lets the deletion pass run
+on the host alone, with no gather-back from the device.
 """
 from __future__ import annotations
 
@@ -124,12 +127,6 @@ class MaintenanceReport:
                 + ("; TT class grew" if self.tt_grew else ""))
 
 
-@jax.jit
-def _device_delete(data: jax.Array, keep: jax.Array, overflow: jax.Array
-                   ) -> E.PRel:
-    return E.compact(data, keep, overflow)
-
-
 def _rows_in(rows: np.ndarray, reference: np.ndarray) -> np.ndarray:
     """Membership mask for (n, w) int32 rows in a reference relation."""
     rows = np.asarray(rows, np.int32)
@@ -169,6 +166,7 @@ class ViewMaintainer:
         self.tt_growths = 0
         self.oracle_batches = 0
         self.delete_scans = 0    # extents visited by deletion passes
+        self.delete_uploads = 0  # device buffers re-uploaded by deletion
         self.device_appends = 0  # scatter_append kernel calls
         self.drift = None  # type: DriftDetector | None
         self._bind(executor)
@@ -342,22 +340,24 @@ class ViewMaintainer:
             if not gone:
                 continue
             prel = ex.device_views[vid]
+            kept = rel.rows[keep]
             if self.engine == "host":
                 # CPU path: defer to one padded re-upload per touched
-                # view at the end of the batch (a memcpy — cheaper than
-                # dispatching the compiled compact)
+                # view at the end of the batch (coalesced with the
+                # insert pass's)
                 self._dirty[vid] = prel.cap
             else:
-                keep_dev = np.zeros(prel.cap, dtype=bool)
-                keep_dev[: len(keep)] = keep
-                ex.device_views[vid] = _device_delete(prel.data,
-                                                      jnp.asarray(keep_dev),
-                                                      prel.overflow)
+                # the device insert pass scatter-appends into this
+                # buffer next: upload the compacted mirror now, padded
+                # into the same capacity class
+                ex.device_views[vid] = E.make_prel(kept, prel.cap)._replace(
+                    overflow=prel.overflow)
+                self.delete_uploads += 1
             # copy-on-write: apply()'s rollback restores a shallow copy
             # of _ext_keys, so entries must be replaced, never mutated
             self._ext_keys[vid] = \
                 self._ext_keys[vid] - set(_row_bytes(rel.rows[~keep]))
-            ex.extents[vid] = R.Relation(rel.rows[keep], rel.cols)
+            ex.extents[vid] = R.Relation(kept, rel.cols)
             report.removed[vid] = gone
 
     # -- TT upload -----------------------------------------------------
@@ -538,6 +538,7 @@ class ViewMaintainer:
             "tt_cap": self.tt_cap,
             "oracle_views": len(self.plans.oracle_vids),
             "delete_scans": self.delete_scans,
+            "delete_uploads": self.delete_uploads,
             "device_appends": self.device_appends,
             "delta_plans": len(self.plans.plans),
             "delta_leaves": len(self.plans.leaves),

@@ -212,6 +212,37 @@ def test_maintainer_delete_only_and_insert_only_batches():
         np.testing.assert_array_equal(got, _extent_oracle(view.cq, ex.store))
 
 
+@pytest.mark.parametrize("with_inserts", [False, True])
+def test_device_delete_pass_uploads_compacted_mirror(with_inserts):
+    # device engine: a view that loses rows gets its compacted host
+    # mirror uploaded into the same capacity class, before the insert
+    # pass scatter-appends after that prefix
+    rng = np.random.default_rng(5)
+    store = _random_store(rng)
+    sess = _session(store, [_chain_cq("q1", 1, 2), _chain_cq("q2", 2, 3)])
+    m = ViewMaintainer(sess.executor,
+                       MaintenanceConfig(delta_cap=64, insert_engine="device"))
+    ex = sess.executor
+    assert not m.plans.oracle_vids
+    before = {vid: (p.cap, bool(p.overflow))
+              for vid, p in ex.device_views.items()}
+    cur = ex.store.triples
+    dels = cur[np.isin(cur[:, 1], [1, 2])][:48]
+    ins = _random_batch(rng, 64) if with_inserts else None
+    report = m.apply(Delta.of(ins, dels))
+    assert report.removed
+    if with_inserts:
+        assert set(report.removed) & set(report.appended)
+    assert m.telemetry()["delete_uploads"] == len(report.removed)
+    for vid, view in ex.state.views.items():
+        prel = ex.device_views[vid]
+        assert (prel.cap, bool(prel.overflow)) == before[vid]
+        m.check_alignment(vid)
+        assert (np.asarray(prel.data)[int(prel.n):] == -1).all()
+        got = np.unique(ex.extents[vid].rows, axis=0)
+        np.testing.assert_array_equal(got, _extent_oracle(view.cq, ex.store))
+
+
 def test_delete_pass_scans_only_inverted_index_candidates():
     rng = np.random.default_rng(21)
     store = _random_store(rng)

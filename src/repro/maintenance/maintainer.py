@@ -548,10 +548,12 @@ class ViewMaintainer:
             "delta_compiles": 0,
             "delta_recompiles": 0,
             "delta_runs": 0,
+            "delta_probe_joins": 0,
         }
         if self._delta_exec is not None:
             dt = self._delta_exec.telemetry()
             t["delta_compiles"] = dt.get("compiles", 0)
             t["delta_recompiles"] = dt.get("recompiles", 0)
             t["delta_runs"] = dt.get("runs", 0)
+            t["delta_probe_joins"] = dt.get("probe_joins", 0)
         return t

@@ -32,11 +32,18 @@ bucket's per-slot maximum capacity (padded rows are `-1`-scrubbed and
 sit beyond the valid count, so operators never see them), which keeps a
 bucket batchable even after one producer bucket has been promoted past
 its siblings.
+
+A join whose right child is a bound-predicate TT scan lowers to a
+`probe` bucket instead when its left side is small: the members' left
+keys are binary-searched in the sorted TT index itself (`engine.probe`),
+so neither the scan buffer nor the build-side sort exists.  The choice
+is static — probe when `left_cap * ceil(log2 tt_rows) < right scan
+cap` — and a scan every consumer of which probes is dropped.
 """
 from __future__ import annotations
 
 import time
-from collections import OrderedDict
+from collections import Counter, OrderedDict
 from dataclasses import dataclass, field
 
 import jax
@@ -53,7 +60,7 @@ CAP_CEIL = 1 << 22
 
 # the program span of one bucket dispatch, by operator kind
 _BUCKET_SPANS = {k: f"rdfviews.query.bucket.{k}"
-                 for k in ("scan", "filter", "join", "project")}
+                 for k in ("scan", "filter", "join", "probe", "project")}
 
 # Default LRU bound of the process-global compile cache.  A long-lived
 # TuningSession.retune() loop churns through bucket shapes; without a
@@ -161,10 +168,11 @@ class Bucket:
     kind: str
     wave: int
     static: tuple                 # structural signature (positions only)
-    cap: int                      # output capacity class (scan/join; 0 else)
+    cap: int                      # output capacity class (0: filter/project)
     node_ids: list[int] = field(default_factory=list)
     promotions: int = 0
-    # scan buckets: per-member constants, stacked + uploaded at build time
+    # scan buckets: per-member pattern constants (prefix, residual);
+    # probe buckets: prefix only; stacked + uploaded at build time
     pvals: jax.Array | None = None
     rvals: jax.Array | None = None
 
@@ -186,22 +194,70 @@ def node_waves(dag: WorkloadDAG) -> list[int]:
     return waves
 
 
+def plan_probes(dag: WorkloadDAG, caps: list[int], scan_specs: dict,
+                join_specs: dict, eff_caps: list[int],
+                tt_rows: int) -> dict[int, tuple]:
+    """The joins that lower to index probes, from static capacities.
+
+    A join qualifies when its right child is a TT scan of a bound
+    predicate that some index orders by the join column right after a
+    bound prefix (`engine.probe_scan_spec`), and probing is the cheaper
+    side: `left_cap * ceil(log2 tt_rows) < right scan cap` (binary
+    searches per left row against scanning and sorting the range).
+    Returns {join nid: (idx_name, prefix, rpos, lcol, residual, keep,
+    self_eq)}, every column in it a triple position except `lcol` and
+    the left halves of `residual`."""
+    log_n = max((int(tt_rows) - 1).bit_length(), 1)
+    out: dict[int, tuple] = {}
+    for node in dag.nodes:
+        if node.kind != "join":
+            continue
+        lid, rid = node.child_ids
+        if dag.nodes[rid].kind != "scan" or eff_caps[lid] * log_n >= caps[rid]:
+            continue
+        lcol, rcol, residual, keep_right = join_specs[node.id]
+        _, _, _, takes, self_eq = scan_specs[rid]
+        spec = E.probe_scan_spec(dag.nodes[rid].spec, takes[rcol])
+        if spec is None:
+            continue
+        idx_name, prefix = spec
+        out[node.id] = (idx_name, prefix, takes[rcol], lcol,
+                        tuple((l, takes[r]) for l, r in residual),
+                        tuple(takes[i] for i in keep_right), self_eq)
+    return out
+
+
 def plan_buckets(dag: WorkloadDAG, caps: list[int], scan_specs: dict,
-                 join_specs: dict) -> tuple[list[Bucket], dict[int, Bucket]]:
+                 join_specs: dict, probes: dict | None = None
+                 ) -> tuple[list[Bucket], dict[int, Bucket]]:
     """Group every non-view node into shape buckets.
 
     `caps` holds the planned output capacity class per node (scan/join;
     unused entries 0).  `scan_specs[nid]` / `join_specs[nid]` hold the
-    static lowering parameters produced by `BucketedProgram`.  Returns
-    (buckets in execution order, node id -> bucket).
+    static lowering parameters produced by `BucketedProgram`, and
+    `probes` the joins lowered to index probes (`plan_probes`); a scan
+    whose every consumer probes is not run at all.  Returns (buckets in
+    execution order, node id -> bucket).
     """
+    probes = probes or {}
+    probed = Counter(dag.nodes[nid].child_ids[1] for nid in probes)
     waves = node_waves(dag)
     by_key: dict[tuple, Bucket] = {}
     node_bucket: dict[int, Bucket] = {}
     for node in dag.nodes:
-        if node.kind == "view":
+        kind = node.kind
+        if kind == "view" or (kind == "scan" and probed[node.id]
+                              == dag.consumers.get(node.id, 0)):
             continue
-        if node.kind == "scan":
+        if node.id in probes:
+            kind = "probe"
+            idx_name, prefix, rpos, lcol, residual, keep, self_eq = \
+                probes[node.id]
+            lw = dag.nodes[node.child_ids[0]].width
+            static = ("probe", idx_name, tuple(c for c, _ in prefix), rpos,
+                      lcol, residual, keep, self_eq, lw)
+            cap = caps[node.id]
+        elif node.kind == "scan":
             idx_name, prefix, residual, takes, self_eq = scan_specs[node.id]
             static = ("scan", idx_name, tuple(c for c, _ in prefix),
                       tuple(c for c, _ in residual), takes, self_eq)
@@ -226,7 +282,7 @@ def plan_buckets(dag: WorkloadDAG, caps: list[int], scan_specs: dict,
         key = (waves[node.id], static, cap)
         bucket = by_key.get(key)
         if bucket is None:
-            bucket = Bucket(kind=node.kind, wave=waves[node.id],
+            bucket = Bucket(kind=kind, wave=waves[node.id],
                             static=static, cap=cap)
             by_key[key] = bucket
         bucket.node_ids.append(node.id)
@@ -286,6 +342,23 @@ def _join_body(static, cap, use_pallas):
     return fn
 
 
+def _probe_body(static, cap):
+    _, _idx_name, prefix_cols, rpos, lcol, residual, keep, self_eq, _lw = \
+        static
+
+    def fn(ldata, ln, lovf, index_data, pvals):
+        def step(carry, xs):
+            ld, ln_, lo, pv = xs
+            prefix = tuple((c, pv[i]) for i, c in enumerate(prefix_cols))
+            return carry, E.probe(E.PRel(ld, ln_, lo), index_data, prefix,
+                                  rpos, lcol, residual, keep, self_eq, cap)
+
+        _, out = lax.scan(step, None, (ldata, ln, lovf, pvals))
+        return out
+
+    return fn
+
+
 def _project_body(static):
     _, idxs, dedupe, _cw = static
 
@@ -318,6 +391,8 @@ def body_builder(bucket: Bucket, use_pallas: bool = False,
         op = _filter_body(bucket.static)
     elif kind == "join":
         op = _join_body(bucket.static, bucket.cap, use_pallas)
+    elif kind == "probe":
+        op = _probe_body(bucket.static, bucket.cap)
     elif kind == "project":
         op = _project_body(bucket.static)
     else:
@@ -423,12 +498,19 @@ class BucketedProgram:
     "materialize": device materialization).  It names every bucket body
     (`body_builder`) and is part of each compile-cache key, so a body
     compiled under one name is never served under another.
+
+    `tt_rows` (the TT index operand's row count) and `view_caps` (view
+    id -> buffer capacity) are the static shapes the program will run
+    on, which decide the joins lowered to probes (`plan_probes`); left
+    out, they are estimated from `stats` and the view estimates.
     """
 
     def __init__(self, dag: WorkloadDAG, stats, view_infos, *,
                  safety: float = 4.0, use_pallas: bool = False,
                  cap_planner=None, ests=None,
-                 carry_caps: dict | None = None, role: str = "workload"):
+                 carry_caps: dict | None = None, role: str = "workload",
+                 tt_rows: int | None = None,
+                 view_caps: dict[int, int] | None = None):
         self.dag = dag
         self.stats = stats
         self.use_pallas = use_pallas
@@ -443,22 +525,28 @@ class BucketedProgram:
             content_keys=self.content_keys)
         self.caps = caps
         self.demands = demands
-        self.buckets, self.node_bucket = plan_buckets(dag, caps, scan_specs,
-                                                      join_specs)
-        # stack per-member scan constants once (they never change)
+        if tt_rows is None:
+            tt_rows = max(int(stats.n_triples), 1)
+        self.probes = plan_probes(dag, caps, scan_specs, join_specs,
+                                  self.static_eff_caps(view_caps), tt_rows)
+        self.buckets, self.node_bucket = plan_buckets(
+            dag, caps, scan_specs, join_specs, self.probes)
+        # stack per-member pattern constants once (they never change);
+        # device-resident once: re-uploading per run would put a host
+        # transfer on every dispatch of the hot path
+        def stack(rows, b):
+            return jnp.asarray(np.asarray(rows, np.int32).reshape(
+                len(b.node_ids), -1))
+
         for b in self.buckets:
             if b.kind == "scan":
-                pv, rv = [], []
-                for nid in b.node_ids:
-                    _, prefix, residual, _, _ = scan_specs[nid]
-                    pv.append([v for _, v in prefix])
-                    rv.append([v for _, v in residual])
-                # device-resident once: re-uploading per run would put a
-                # host transfer on every dispatch of the hot path
-                b.pvals = jnp.asarray(np.asarray(pv, np.int32).reshape(
-                    len(b.node_ids), -1))
-                b.rvals = jnp.asarray(np.asarray(rv, np.int32).reshape(
-                    len(b.node_ids), -1))
+                b.pvals = stack([[v for _, v in scan_specs[nid][1]]
+                                 for nid in b.node_ids], b)
+                b.rvals = stack([[v for _, v in scan_specs[nid][2]]
+                                 for nid in b.node_ids], b)
+            elif b.kind == "probe":
+                b.pvals = stack([[v for _, v in self.probes[nid][1]]
+                                 for nid in b.node_ids], b)
         # telemetry (per program; the cache itself is process-global)
         self.cache_hits = 0
         self.cache_misses = 0
@@ -571,6 +659,12 @@ class BucketedProgram:
             rd, rn, ro = self._gather_slot(res, rkids, rcap)
             args = (ld, ln, lo, rd, rn, ro)
             out_cap = bucket.cap
+        elif bucket.kind == "probe":
+            lkids = [dag.nodes[nid].child_ids[0] for nid in bucket.node_ids]
+            lcap = max(eff_cap[c] for c in lkids)
+            ld, ln, lo = self._gather_slot(res, lkids, lcap)
+            args = (ld, ln, lo, tt[bucket.static[1]], bucket.pvals)
+            out_cap = bucket.cap
         elif bucket.kind == "project":
             kids = [dag.nodes[nid].child_ids[0] for nid in bucket.node_ids]
             cap = max(eff_cap[c] for c in kids)
@@ -652,23 +746,21 @@ class BucketedProgram:
         """Effective buffer capacity per node, computed exactly like
         `execute` propagates it but without touching the device: views
         take `view_caps[vid]` (falling back to a capacity class planned
-        from the estimated extent rows), scans/joins their bucket's
-        capacity class, filters/projects the max of their child caps."""
+        from the estimated extent rows), scans/joins their capacity
+        class (promotions included), filters/projects the max of their
+        child caps."""
         view_caps = view_caps or {}
         eff: list[int] = [0] * len(self.dag.nodes)
-        for node in self.dag.nodes:
+        for node in self.dag.nodes:  # children come before their parents
             if node.kind == "view":
                 eff[node.id] = view_caps.get(
                     node.spec,
                     cost_mod.capacity_for(self.ests[node.id].rows,
                                           safety=1.0))
-        for bucket in self.buckets:
-            for nid in bucket.node_ids:
-                node = self.dag.nodes[nid]
-                if bucket.kind in ("scan", "join"):
-                    eff[nid] = bucket.cap
-                else:  # filter/project pass through their child's cap
-                    eff[nid] = max(eff[c] for c in node.child_ids)
+            elif node.kind in ("scan", "join"):
+                eff[node.id] = self.caps[node.id]
+            else:  # filter/project pass through their child's cap
+                eff[node.id] = max(eff[c] for c in node.child_ids)
         return eff
 
     def abstract_args(self, bucket: Bucket, n_tt: int,
@@ -676,7 +768,7 @@ class BucketedProgram:
         """ShapeDtypeStructs of the operands `_run_bucket` would stack
         for this bucket — enough to trace the body with `make_jaxpr` /
         `eval_shape` without any device data.  `n_tt` is the triple
-        count (scan buckets read one sorted (n_tt, 3) index)."""
+        count (scan and probe buckets read one sorted (n_tt, 3) index)."""
         dag = self.dag
         B = len(bucket.node_ids)
         i32, b1 = np.dtype(np.int32), np.dtype(bool)
@@ -692,6 +784,12 @@ class BucketedProgram:
             return (jax.ShapeDtypeStruct((n_tt, 3), i32),
                     jax.ShapeDtypeStruct((B, pw), i32),
                     jax.ShapeDtypeStruct((B, rw), i32))
+        if bucket.kind == "probe":
+            kids = [dag.nodes[nid].child_ids[0] for nid in bucket.node_ids]
+            cap = max(eff_cap[c] for c in kids)
+            return slot(kids, cap, bucket.static[-1]) + (
+                jax.ShapeDtypeStruct((n_tt, 3), i32),
+                jax.ShapeDtypeStruct(bucket.pvals.shape, i32))
         if bucket.kind == "filter":
             kids = [dag.nodes[nid].child_ids[0] for nid in bucket.node_ids]
             cap = max(eff_cap[c] for c in kids)
@@ -730,4 +828,5 @@ class BucketedProgram:
             "bucket_compile_seconds": self.compile_seconds,
             "bucket_compile_log": list(self.compile_log),
             "bucket_promotions": sum(b.promotions for b in self.buckets),
+            "probe_joins": len(self.probes),
         }

@@ -12,7 +12,9 @@ from the same cardinality estimates the quality function uses
 Joins are sort + `searchsorted` + bounded expansion via
 `jnp.repeat(..., total_repeat_length=cap)` — the TPU-native replacement
 for dynamic hash tables.  The probe phase can be delegated to the Pallas
-kernel (`kernels/ops.py`) with `use_pallas=True`.
+kernel (`kernels/ops.py`) with `use_pallas=True`.  A join whose right
+side is a bound-predicate triple pattern can instead `probe` a sorted TT
+index in place: binary searches per left key, no scan buffer, no sort.
 """
 from __future__ import annotations
 
@@ -22,6 +24,7 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from repro import trace
 from repro.core.queries import Const, Var
@@ -85,6 +88,39 @@ def filter_eq(rel: PRel, col: int, value) -> PRel:
     return compact(rel.data, mask, rel.overflow)
 
 
+def _expand(left: PRel, lkeys: jax.Array, lo: jax.Array, counts: jax.Array,
+            rrows: jax.Array, residual: tuple[tuple[int, int], ...],
+            keep_right: tuple[int, ...], out_cap: int, overflow: jax.Array,
+            self_eq: tuple[tuple[int, int], ...] = ()) -> PRel:
+    """The output tail shared by `join` and `probe`: left row i matches
+    rows `[lo[i], lo[i] + counts[i])` of `rrows`.  Expands the matches
+    into `out_cap` rows (`repeat` with a static length), keeps those
+    whose right row has equal columns at each `self_eq` pair and that
+    pass the residual `(left col, right col)` pairs, outputs left's
+    columns then right's `keep_right`, and latches `overflow` when the
+    matches exceed `out_cap`."""
+    counts = jnp.where(lkeys == INVALID, 0, counts)
+    total = jnp.sum(counts)
+    offsets = jnp.cumsum(counts) - counts  # exclusive prefix
+    left_idx = jnp.repeat(
+        jnp.arange(left.cap, dtype=jnp.int32), counts, total_repeat_length=out_cap
+    )
+    pos = jnp.arange(out_cap, dtype=jnp.int32)
+    within = pos - offsets[left_idx]
+    right_idx = jnp.clip(lo[left_idx] + within, 0, rrows.shape[0] - 1)
+    valid = pos < jnp.minimum(total, out_cap)
+
+    lrows = left.data[left_idx]
+    rrows = rrows[right_idx]
+    for a, b in self_eq:
+        valid = valid & (rrows[:, a] == rrows[:, b])
+    for lc, rc in residual:
+        valid = valid & (lrows[:, lc] == rrows[:, rc])
+    out = jnp.concatenate([lrows, rrows[:, list(keep_right)]], axis=1) if keep_right \
+        else lrows
+    return compact(out, valid, overflow | (total > out_cap))
+
+
 def join(left: PRel, right: PRel, lcol: int, rcol: int,
          residual: tuple[tuple[int, int], ...], keep_right: tuple[int, ...],
          out_cap: int, use_pallas: bool = False,
@@ -112,31 +148,78 @@ def join(left: PRel, right: PRel, lcol: int, rcol: int,
         from repro.kernels import ops as kops
 
         lo, counts = kops.join_count(lkeys, rkeys_sorted)
-        hi = lo + counts
     else:
         lo = jnp.searchsorted(rkeys_sorted, lkeys, side="left").astype(jnp.int32)
         hi = jnp.searchsorted(rkeys_sorted, lkeys, side="right").astype(jnp.int32)
         counts = hi - lo
-    counts = jnp.where(lkeys == INVALID, 0, counts)
+    return _expand(left, lkeys, lo, counts, rsorted, residual, keep_right,
+                   out_cap, left.overflow | right.overflow)
 
-    total = jnp.sum(counts)
-    offsets = jnp.cumsum(counts) - counts  # exclusive prefix
-    left_idx = jnp.repeat(
-        jnp.arange(left.cap, dtype=jnp.int32), counts, total_repeat_length=out_cap
-    )
-    pos = jnp.arange(out_cap, dtype=jnp.int32)
-    within = pos - offsets[left_idx]
-    right_idx = jnp.clip(lo[left_idx] + within, 0, right.cap - 1)
-    valid = pos < jnp.minimum(total, out_cap)
 
-    lrows = left.data[left_idx]
-    rrows = rsorted[right_idx]
-    for lc, rc in residual:
-        valid = valid & (lrows[:, lc] == rrows[:, rc])
-    out = jnp.concatenate([lrows, rrows[:, list(keep_right)]], axis=1) if keep_right \
-        else lrows
-    overflow = left.overflow | right.overflow | (total > out_cap)
-    return compact(out, valid, overflow)
+def _bisect(index_data: jax.Array, cols: tuple[int, ...], keys: tuple,
+            lo: jax.Array, hi: jax.Array, side: str) -> jax.Array:
+    """Fixed-iteration binary search in rows `[lo, hi)` of a sorted
+    index, vectorised over the shape of `lo`: the first row whose
+    `cols` compare lexicographically `>= keys` (`side="left"`) or
+    `> keys` (`side="right"`).  Rows outside `[lo, hi)` are never
+    read; `n.bit_length()` halvings cover any range of an (n, 3)
+    index, so the loop count is static."""
+    n = index_data.shape[0]
+    keys = tuple(jnp.asarray(k, jnp.int32) for k in keys)
+
+    def before(at):  # row `at` < keys ("left") or <= keys ("right")
+        out = None
+        # one element gather per column: a gather of whole rows would
+        # make the TPU relayout the (n, 3) index first
+        for c, k in reversed(tuple(zip(cols, keys))):
+            v = index_data[at, c]
+            if out is None:
+                out = (v < k) if side == "left" else (v <= k)
+            else:
+                out = (v < k) | ((v == k) & out)
+        return out
+
+    def step(_, carry):
+        lo, hi = carry
+        mid = lo + (hi - lo) // 2
+        go = before(jnp.clip(mid, 0, max(n - 1, 0)))
+        live = lo < hi
+        return (jnp.where(live & go, mid + 1, lo),
+                jnp.where(live & ~go, mid, hi))
+
+    lo, _ = lax.fori_loop(0, max(n.bit_length(), 1), step, (lo, hi))
+    return lo
+
+
+def probe(left: PRel, index_data: jax.Array,
+          prefix: tuple[tuple[int, int], ...], rpos: int, lcol: int,
+          residual: tuple[tuple[int, int], ...], keep: tuple[int, ...],
+          self_eq: tuple[tuple[int, int], ...], out_cap: int) -> PRel:
+    """Equi-join of `left` with a triple pattern read in place from one
+    sorted TT index, with no scan buffer and no build-side sort.
+
+    `prefix` binds the index's leading columns as in `scan_pattern`;
+    the index orders the prefix's rows by triple position `rpos` next,
+    so each left key's matches are one contiguous range, found by two
+    binary searches inside the prefix's range.  `residual` pairs a left
+    column with a triple position; `keep` lists the triple positions
+    output after left's columns; `self_eq` pairs the positions of a
+    variable the pattern repeats.  Rows, overflow and set semantics are those
+    of `join(left, scan_pattern(...), ...)`; the matches are gathered
+    straight from the index by the same tail (`_expand`)."""
+    cols = tuple(c for c, _ in prefix)
+    vals = tuple(v for _, v in prefix)
+    n = jnp.int32(index_data.shape[0])
+    lo0 = _bisect(index_data, cols, vals, jnp.int32(0), n, "left")
+    hi0 = _bisect(index_data, cols, vals, lo0, n, "right")
+    lkeys = jnp.where(_valid_mask(left), left.data[:, lcol], INVALID)
+    first = jnp.full(lkeys.shape, lo0, jnp.int32)
+    last = jnp.full(lkeys.shape, hi0, jnp.int32)
+    lo = _bisect(index_data, (rpos,), (lkeys,), first, last, "left")
+    hi = _bisect(index_data, (rpos,), (lkeys,), lo, last, "right")
+
+    return _expand(left, lkeys, lo, hi - lo, index_data, residual, keep,
+                   out_cap, left.overflow, self_eq)
 
 
 def project(rel: PRel, cols: tuple[int, ...], dedupe: bool) -> PRel:
@@ -274,6 +357,23 @@ def atom_scan_spec(atom, prefer_sorted: str | None = None):
                 first[t.name] = posn
                 takes.append(posn)
     return best_idx, best_prefix, residual, tuple(takes), tuple(self_eq), sorted_by
+
+
+def probe_scan_spec(atom, rpos: int):
+    """The index a `probe` reads a bound-predicate pattern through when
+    joining on triple position `rpos`: one whose leading columns are
+    exactly the bound positions (the prefix) and whose next column is
+    `rpos`, so each join key's rows are one contiguous range.  With the
+    predicate bound such an index always exists (`pso`/`pos` for one
+    bound position, `pso`/`spo` or `pos` for two).  Returns (idx_name,
+    prefix), or None when the predicate is a variable."""
+    if not isinstance(atom.p, Const):
+        return None
+    bound = {i: t.id for i, t in enumerate(atom.terms()) if isinstance(t, Const)}
+    for idx_name, cols in _INDEX_ORDERS.items():
+        if set(cols[:len(bound)]) == set(bound) and cols[len(bound)] == rpos:
+            return idx_name, tuple((c, bound[c]) for c in cols[:len(bound)])
+    return None
 
 
 def range_cardinality(atom, prefix, stats) -> float:

@@ -234,14 +234,19 @@ class WorkloadExecutor:
         self._jit = jax.jit(fn)
         self.compiles += 1
 
-    def _program(self) -> BucketedProgram:
+    def _program(self, tt=None, views=None) -> BucketedProgram:
+        """The bucketed program, lowered on first use against the shapes
+        of the operands it is first run on (`tt`, `views`), where given."""
         if self._prog is None:
             self._fire("compile")
             self._prog = BucketedProgram(
                 self.dag, self.stats, self.view_infos, safety=self.safety,
                 use_pallas=self.use_pallas, cap_planner=self.cap_planner,
                 ests=self._ensure_ests(), carry_caps=self.carry_caps,
-                role=self.role)
+                role=self.role,
+                tt_rows=(next(iter(tt.values())).shape[0] if tt else None),
+                view_caps=({vid: rel.cap for vid, rel in views.items()}
+                           if views else None))
             self.caps = self._prog.caps
             self.compiles += 1
         return self._prog
@@ -256,7 +261,7 @@ class WorkloadExecutor:
         return self._run_unrolled(tt, views)
 
     def _run_bucketed(self, tt, views) -> dict[str, E.PRel]:
-        prog = self._program()
+        prog = self._program(tt, views)
         attempt = 0
         while True:
             roots, own = prog.execute(tt, views)
@@ -368,7 +373,8 @@ class WorkloadExecutor:
         t.update(buckets=0, bucket_signatures=0, bucket_compiles=0,
                  bucket_cache_hits=0, bucket_cache_misses=0,
                  bucket_compile_seconds=0.0,
-                 bucket_compile_log=[], bucket_promotions=0)
+                 bucket_compile_log=[], bucket_promotions=0,
+                 probe_joins=0)
         if self._prog is not None:
             t.update(self._prog.telemetry())
         t["compile_cache"] = compile_cache().stats()

@@ -145,6 +145,46 @@ def test_bucket_bodies_are_named_by_role_and_kind(uni, role):
     assert {"scan", "join"} <= kinds
 
 
+@pytest.mark.parametrize("left_cap, probed", [(128, True), (1 << 16, False)])
+def test_join_on_a_large_scan_lowers_to_a_probe(uni, left_cap, probed):
+    """A join of a small left side with a bound-predicate scan becomes a
+    `probe` bucket exactly when left_cap * ceil(log2 tt_rows) < the
+    scan's cap; the probed scan, consumed by nothing else, is not run,
+    and the answer is the sort join's."""
+    from repro.query.cost import RelInfo
+    from repro.query.plan import EquiJoin, TTScan, ViewRef
+
+    d = uni.dictionary
+    x, y, z = Var("x"), Var("y"), Var("z")
+    atom = Atom(x, Const(d.lookup("ub:takesCourse")), y)
+    dag = build_dag({"q": EquiJoin(ViewRef(0, ("x", "z")), TTScan(atom),
+                                   (("x", "x"),))})
+    n_tt = len(uni.store)
+    assert left_cap * (n_tt - 1).bit_length() < 4096 or not probed
+    left = R.evaluate_cq(CQ((x, y), (Atom(
+        x, Const(d.lookup("ub:memberOf")), y),)), uni.store)
+    info = {0: R.Relation(left.rows, ("x", "z"))}
+    rows = float(len(left.rows))
+    prog = BucketedProgram(dag, uni.store.stats,
+                           {0: RelInfo(rows, {0: rows, 1: rows})},
+                           cap_planner=lambda node, rows: 4096,
+                           role="delta", tt_rows=n_tt,
+                           view_caps={0: left_cap})
+    kinds = sorted(b.kind for b in prog.buckets)
+    assert kinds == (["probe"] if probed else ["join", "scan"])
+    assert prog.telemetry()["probe_joins"] == int(probed)
+    for b in prog.buckets:
+        specs = prog.abstract_args(b, n_tt, prog.static_eff_caps({0: left_cap}))
+        text = jax.jit(body_builder(b, False, "delta")).lower(*specs).as_text()
+        assert f"jit_delta_{b.kind}" in text
+    roots, own = prog.execute(E.tt_device_indexes(uni.store),
+                              {0: E.make_prel(left.rows, left_cap)})
+    assert not own.any()
+    got = {tuple(r) for r in E.to_numpy(roots["q"]).tolist()}
+    want = R.execute(dag.nodes[dag.roots["q"]].plan, uni.store, info)
+    assert got == want.as_set() and got
+
+
 def test_roles_get_distinct_cache_keys(uni):
     """The same bucket under two roles is two cache entries: a body
     compiled under one name is never served under the other."""

@@ -243,6 +243,43 @@ def test_device_delete_pass_uploads_compacted_mirror(with_inserts):
         np.testing.assert_array_equal(got, _extent_oracle(view.cq, ex.store))
 
 
+def test_device_insert_engine_probes_the_tt_index():
+    # a store large enough that each predicate's range dwarfs a delta
+    # relation: the delta program's joins lower to index probes, and
+    # across enrol (insert) and withdraw (delete) batches the device
+    # engine's extents equal the host engine's and the oracle's; the
+    # serving program reads views only and never probes
+    rng = np.random.default_rng(17)
+    store = _random_store(rng, n=20000, n_ids=2000)
+    cqs = [_chain_cq("q1", 1, 2), _chain_cq("q2", 2, 3)]
+    dev, host = _session(store, cqs), _session(store, cqs)
+    m_dev = ViewMaintainer(dev.executor, MaintenanceConfig(
+        delta_cap=64, insert_engine="device"))
+    m_host = ViewMaintainer(host.executor, MaintenanceConfig(
+        delta_cap=64, insert_engine="host"))
+    for step in range(4):
+        ins = _random_batch(rng, 48, n_ids=2000) if step != 1 else None
+        cur = dev.executor.store.triples
+        dels = (cur[rng.choice(len(cur), 40, replace=False)]
+                if step != 0 else None)
+        r_dev = m_dev.apply(Delta.of(ins, dels))
+        m_host.apply(Delta.of(ins, dels))
+        assert r_dev.appended or ins is None
+    for vid, view in dev.executor.state.views.items():
+        m_dev.check_alignment(vid)
+        got = np.unique(dev.executor.extents[vid].rows, axis=0)
+        np.testing.assert_array_equal(
+            got, np.unique(host.executor.extents[vid].rows, axis=0))
+        np.testing.assert_array_equal(
+            got, _extent_oracle(view.cq, dev.executor.store))
+    t = m_dev.telemetry()
+    assert t["insert_engine"] == "device" and t["delta_recompiles"] == 0
+    assert t["delta_probe_joins"] > 0
+    for q in dev.workload:
+        assert dev.answer(q.name) == dev.executor.answer_group_direct(q.name)
+    assert dev.executor.workload.telemetry()["probe_joins"] == 0
+
+
 def test_delete_pass_scans_only_inverted_index_candidates():
     rng = np.random.default_rng(21)
     store = _random_store(rng)

@@ -1,17 +1,15 @@
 """Compile-time scaling of the shape-bucketed workload executor.
 
-The unrolled fused program traces one closure per DAG node, so its XLA
-compile time grows linearly with workload size; the bucketed lowering
-(query/buckets.py) compiles one `lax.scan` body per distinct shape, so
-its compile time should stay near-flat as the workload grows from 22 to
-1000+ members drawn from a fixed template vocabulary.
+The bucketed lowering (query/buckets.py) compiles one `lax.scan` body
+per distinct shape, so its compile time should stay near-flat as the
+workload grows from 22 to 1000+ members drawn from a fixed template
+vocabulary (a lowering that traced one closure per DAG node would grow
+linearly instead).
 
 The sweep synthesizes distinct LUBM-vocabulary queries (same shapes,
 different constants), clears the persistent compile cache at every
 point (cold-compile measurement), runs the bucketed executor, and
 checks every answer bit-identically against the numpy reference engine.
-An unrolled A/B leg runs at the small end of the sweep — past that its
-compile time is the wall this benchmark exists to remove.
 
 Gate (CI runs the quick sweep): cold compile time at the largest point
 must stay within `THRESHOLD`x the smallest point — super-linear compile
@@ -129,11 +127,11 @@ def main(lines: list[str]) -> None:
     if quick:
         uni = generate(n_universities=1, seed=0, dept_per_univ=2,
                        prof_per_dept=4, stud_per_dept=12, course_per_dept=5)
-        sweep, unroll_cap = [8, 32, 64], 32
+        sweep = [8, 32, 64]
     else:
         uni = generate(n_universities=2, seed=0, dept_per_univ=4,
                        prof_per_dept=4, stud_per_dept=20, course_per_dept=8)
-        sweep, unroll_cap = [22, 64, 128, 256, 512, 1000], 64
+        sweep = [22, 64, 128, 256, 512, 1000]
     tt = E.tt_device_indexes(uni.store)
 
     metrics: dict = {"quick": int(quick), "threshold": THRESHOLD,
@@ -172,17 +170,6 @@ def main(lines: list[str]) -> None:
             f"compile_scale.bucketed.n{n}", steady_us,
             f"compile_s={t['bucket_compile_seconds']:.2f} "
             f"buckets={t['buckets']} dag_nodes={st['dag_nodes']}"))
-
-        if n <= unroll_cap:  # A/B: linear-compile reference path
-            wl_u = WorkloadExecutor(dag, uni.store.stats, {},
-                                    max_retries=24, mode="unrolled")
-            t0 = time.perf_counter()
-            roots_u = wl_u.run(tt, {})
-            unrolled_s = time.perf_counter() - t0
-            assert check_exact(uni, qs, roots_u) == 0
-            metrics[f"unrolled_first_run_s_{n}"] = unrolled_s
-            lines.append(emit(f"compile_scale.unrolled.n{n}", 0.0,
-                              f"first_run_s={unrolled_s:.2f}"))
 
     ratio = compile_s[sweep[-1]] / max(compile_s[sweep[0]], 1e-9)
     metrics["compile_ratio"] = ratio

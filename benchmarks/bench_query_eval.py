@@ -3,27 +3,40 @@
 Part 1 (the demo's performance claim): per-query latency answered from
 the triple table vs from the wizard's materialized views.
 
-Part 2 (workload-level compilation): the per-query jitted path — one
-XLA program per workload member — vs the fused shared-subplan executor
-— ONE program for the entire workload (query/dag.py + workload.py).
-Reports compile count, compile time, per-workload latency, and the
-DAG's shared-node hit rate; the speedup lands in BENCH_query_eval.json.
+Part 2 (workload-level compilation): one-member programs — each
+workload member lowered alone, the serving ladder's tier-1 form — vs
+the fused shared-subplan program — ONE program for the entire workload
+(query/dag.py + workload.py).  Both legs run the same bucketed lowering
+(query/buckets.py), so the difference isolates subplan sharing and
+single-pass dispatch.  Reports compile count, compile time, per-workload
+latency, and the DAG's shared-node hit rate; the speedup lands in
+BENCH_query_eval.json.
 """
 from __future__ import annotations
 
 import time
-
-import jax
 
 from benchmarks.bench_common import (emit, quick_mode, time_us,
                                      write_bench_json)
 from repro.core.search import SearchConfig
 from repro.core.wizard import WizardConfig, tune
 from repro.query import engine as E
+from repro.query.buckets import clear_compile_cache
 from repro.query.dag import build_dag
 from repro.query.plan import plan_for_cq
 from repro.query.workload import WorkloadExecutor
 from repro.rdf.generator import generate, lubm_workload
+
+
+def _one_member(name: str, plan, stats) -> WorkloadExecutor:
+    """`plan` lowered alone over the triple table (no views)."""
+    return WorkloadExecutor(build_dag({name: plan}), stats, {})
+
+
+def _run_all(programs, tt, views) -> None:
+    for wl in programs:
+        roots = wl.run(tt, views)
+        next(iter(roots.values())).n.block_until_ready()
 
 
 def main(lines: list[str]) -> None:
@@ -45,22 +58,12 @@ def main(lines: list[str]) -> None:
         # baseline: every reformulation member evaluated over the TT
         members = [m for m in rep.result.best.queries
                    if m.name in rep.groups[q.name]]
-        base_fns = []
-        for m in members:
-            fn = E.build_executor(plan_for_cq(m), uni.store.stats, {})
-            base_fns.append(jax.jit(fn))
+        base = [_one_member(m.name, plan_for_cq(m), uni.store.stats)
+                for m in members]
+        views = [ex.per_query_program(name) for name in rep.groups[q.name]]
 
-        def run_base():
-            for f in base_fns:
-                f(tt, {}).n.block_until_ready()
-
-        def run_views():
-            for name in rep.groups[q.name]:
-                fn, _ = ex._fns[name]
-                fn(ex.tt, ex.device_views).n.block_until_ready()
-
-        us_base = time_us(run_base)
-        us_views = time_us(run_views)
+        us_base = time_us(lambda: _run_all(base, tt, {}))
+        us_views = time_us(lambda: _run_all(views, ex.tt, ex.device_views))
         speedups.append(us_base / max(us_views, 1e-9))
         lines.append(emit(f"query_eval.{q.name}.tt", us_base,
                           f"members={len(members)}"))
@@ -73,68 +76,49 @@ def main(lines: list[str]) -> None:
     lines.append(emit("query_eval.geomean_speedup", 0.0, f"{geo:.2f}x"))
 
     # ------------------------------------------------------------------
-    # part 2: per-query compilation vs fused workload executor
+    # part 2: one-member programs vs the fused workload program
     # (both over the no-views baseline plans: identical physical work,
-    #  so the delta isolates sharing + single-dispatch)
+    #  so the delta isolates sharing + single-pass dispatch)
     # ------------------------------------------------------------------
     members = list(rep.result.best.queries)
     plans = {m.name: plan_for_cq(m) for m in members}
 
-    # per-query path: one XLA program per member
+    # per-query leg: one program per member, cold compile cache
+    clear_compile_cache()
+    per_q = [_one_member(name, p, uni.store.stats)
+             for name, p in plans.items()]
     t0 = time.perf_counter()
-    per_q = [jax.jit(E.build_executor(p, uni.store.stats, {}))
-             for p in plans.values()]
-    for f in per_q:  # first call = compile
-        f(tt, {}).n.block_until_ready()
+    _run_all(per_q, tt, {})  # first run = compile
     perq_compile_us = (time.perf_counter() - t0) * 1e6
-    perq_compiles = len(per_q)
+    perq_compiles = sum(wl.compiles for wl in per_q)
+    perq_bucket_compiles = sum(wl.telemetry()["bucket_compiles"]
+                               for wl in per_q)
+    perq_us = time_us(lambda: _run_all(per_q, tt, {}))
 
-    def run_per_query():
-        for f in per_q:
-            f(tt, {}).n.block_until_ready()
-
-    perq_us = time_us(run_per_query)
-
-    # fused path: one program for the whole workload.  The A/B leg runs
-    # unrolled — this benchmark isolates subplan sharing + single
-    # dispatch against per-query compilation at matched lowering; the
-    # bucketed lowering's compile-time scaling (and its per-run driver
-    # overhead) is measured separately in bench_compile_scale.
+    # fused leg: one program for the whole workload, cold compile cache
+    clear_compile_cache()
     dag = build_dag(plans)
-    wl = WorkloadExecutor(dag, uni.store.stats, {}, mode="unrolled")
+    wl = WorkloadExecutor(dag, uni.store.stats, {})
     t0 = time.perf_counter()
     wl.run(tt, {})  # compile + first run (adaptive driver)
     fused_compile_us = (time.perf_counter() - t0) * 1e6
     fused_compiles = wl.compiles
-
-    def run_fused():
-        roots = wl.run(tt, {})
-        next(iter(roots.values())).n.block_until_ready()
-
-    fused_us = time_us(run_fused)
-
-    # bucketed steady-state latency, for the record (same DAG/answers)
-    wl_b = WorkloadExecutor(build_dag(plans), uni.store.stats, {})
-    wl_b.run(tt, {})
-
-    def run_bucketed():
-        roots = wl_b.run(tt, {})
-        next(iter(roots.values())).n.block_until_ready()
-
-    bucketed_us = time_us(run_bucketed)
+    fused_us = time_us(lambda: _run_all([wl], tt, {}))
+    ft = wl.telemetry()
     st = dag.stats()
     workload_speedup = perq_us / max(fused_us, 1e-9)
 
     lines.append(emit("query_eval.workload.per_query", perq_us,
-                      f"compiles={perq_compiles}"))
+                      f"compiles={perq_compiles} "
+                      f"bucket_compiles={perq_bucket_compiles}"))
     lines.append(emit("query_eval.workload.fused", fused_us,
                       f"compiles={fused_compiles} "
+                      f"bucket_compiles={ft['bucket_compiles']} "
+                      f"buckets={ft['buckets']} "
                       f"shared={st['shared_nodes']} "
                       f"hit_rate={st['hit_rate']:.2f}"))
     lines.append(emit("query_eval.workload.speedup", 0.0,
                       f"{workload_speedup:.2f}x"))
-    lines.append(emit("query_eval.workload.bucketed", bucketed_us,
-                      f"buckets={wl_b.telemetry()['buckets']}"))
 
     assert fused_compiles < perq_compiles, (
         "fused executor must compile strictly fewer programs")
@@ -144,14 +128,14 @@ def main(lines: list[str]) -> None:
         "workload_members": len(members),
         "per_query_compile_us": perq_compile_us,
         "per_query_compiles": perq_compiles,
+        "per_query_bucket_compiles": perq_bucket_compiles,
         "per_query_workload_us": perq_us,
         "fused_compile_us": fused_compile_us,
         "fused_compiles": fused_compiles,
+        "fused_bucket_compiles": ft["bucket_compiles"],
+        "fused_buckets": ft["buckets"],
         "fused_workload_us": fused_us,
         "fused_recompiles": wl.recompiles,
-        "bucketed_workload_us": bucketed_us,
-        "bucketed_buckets": wl_b.telemetry()["buckets"],
-        "bucketed_compile_s": wl_b.telemetry()["bucket_compile_seconds"],
         "dag_nodes": st["dag_nodes"],
         "tree_nodes": st["tree_nodes"],
         "shared_nodes": st["shared_nodes"],

@@ -26,7 +26,8 @@ from __future__ import annotations
 from repro.analysis.findings import Finding
 from repro.errors import InvariantViolation
 from repro.query import cost as cost_mod
-from repro.query.buckets import CAP_CEIL, BucketedProgram, plan_capacities
+from repro.query.buckets import BucketedProgram, plan_capacities
+from repro.query.cost import CAP_CEIL
 from repro.query.dag import WorkloadDAG
 
 HEADROOM_WARN = 2.0  # flag sized buffers with < 2x slack over the estimate

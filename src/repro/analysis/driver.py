@@ -104,14 +104,10 @@ def verify_session(session, *, n_tt: int | None = None) -> AnalysisReport:
     if ex is not None and not session.pending:
         expected = set(ex.state.rewritings) - ex._oracle_names
         stats = ex.store.stats
-        program = None
-        view_caps = None
-        if ex.workload.mode == "bucketed":
-            program = ex.workload._program()
-            view_caps = {vid: int(rel.data.shape[0])
-                         for vid, rel in ex.device_views.items()}
+        view_caps = {vid: int(rel.data.shape[0])
+                     for vid, rel in ex.device_views.items()}
         report = analyze_workload(
-            ex.dag, stats, ex.infos, program=program,
+            ex.dag, stats, ex.infos, program=ex.workload._program(),
             n_tt=n_tt if n_tt is not None else int(ex.tt["spo"].shape[0]),
             view_caps=view_caps, expected_members=expected)
         maintainer = getattr(session, "_maintainer", None)

@@ -37,7 +37,7 @@ import math
 
 from repro.analysis.findings import Finding
 from repro.query import cost as cost_mod
-from repro.query.buckets import CAP_CEIL
+from repro.query.cost import CAP_CEIL
 
 # warn when a capacity class is projected to be outgrown within this
 # many update batches at the configured rate

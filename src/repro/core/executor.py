@@ -2,18 +2,19 @@
 
 The production path is *workload-level*: every member rewriting
 (including reformulation-group members) is canonicalized into one
-shared-subplan DAG (`query/dag.py`) and compiled into a single jitted
-program (`query/workload.py`) that answers the entire workload in one
-device call — each shared subtree computed once.  Capacity overflows no
-longer raise: the adaptive driver doubles the offending node's buffer
-and recompiles under a bounded retry budget (telemetry on
-`executor.workload`).
+shared-subplan DAG (`query/dag.py`) and lowered into one shape-bucketed
+program (`query/buckets.py`, driven by `query/workload.py`) that answers
+the entire workload in one pass — each shared subtree computed once.
+Capacity overflows do not raise: the adaptive driver promotes the
+offending bucket to the next capacity class and recompiles it under a
+bounded retry budget (telemetry on `executor.workload`).
 
 Paths with identical answers:
-  * `answer(name)` / `answer_workload()` — fused JAX engine over
-    materialized padded views (adaptive, jitted once per workload),
-  * `answer_per_query(name)` — legacy per-query jitted tree compilation
-    (kept for A/B benchmarks; raises on overflow like the old engine),
+  * `answer(name)` / `answer_workload()` — the fused program over
+    materialized padded views (adaptive, built once per workload),
+  * `answer_group_per_query(name)` — each member's rewriting lowered
+    alone through the same bucketed lowering (no shared subplans; the
+    serving ladder's tier 1),
   * `answer_direct(name)` — oracle evaluation over the raw triple table
     (the paper's "before tuning" baseline).
 
@@ -26,7 +27,6 @@ from __future__ import annotations
 
 from dataclasses import dataclass
 
-import jax
 import numpy as np
 
 from repro import trace
@@ -67,7 +67,6 @@ class QueryExecutor:
                  use_pallas: bool = False, safety: float = 4.0,
                  max_retries: int = 12, cap_planner=None,
                  device_materialize: bool = False,
-                 workload_mode: str = "bucketed",
                  fault_hook=None):
         self.fault_hook = fault_hook
         self.store = store
@@ -78,16 +77,15 @@ class QueryExecutor:
         self._max_retries = max_retries
         self._cap_planner = cap_planner
         self._device_materialize = device_materialize
-        self._workload_mode = workload_mode
         self._queries = {q.name: q for q in state.queries}
 
         # ---- fused workload path: one DAG + one jitted program --------
         self._build_dag()
         self._load_device_state(store)
 
-        # legacy per-query path: built lazily on first access (benchmarks
-        # and A/B tests only; the production path never compiles it)
-        self.__fns = None
+        # tier 1 of the serving ladder: one-member programs, built on
+        # first use (a healthy server never builds them)
+        self._per_query: dict[str, WorkloadExecutor] = {}
 
     def _build_dag(self) -> None:
         device_plans = {}
@@ -116,8 +114,8 @@ class QueryExecutor:
         self.workload = WorkloadExecutor(
             self.dag, store.stats, self.infos, safety=self._safety,
             use_pallas=self._use_pallas, max_retries=self._max_retries,
-            cap_planner=self._cap_planner, mode=self._workload_mode,
-            carry_caps=carry_caps, fault_hook=self.fault_hook,
+            cap_planner=self._cap_planner, carry_caps=carry_caps,
+            fault_hook=self.fault_hook,
         )
         self._results: dict[str, np.ndarray] | None = None
 
@@ -148,7 +146,7 @@ class QueryExecutor:
         self.tt = snap.tt
         self.workload = snap.workload
         self._results = snap.results
-        self.__fns = None
+        self._per_query = {}
 
     def set_fault_hook(self, hook) -> None:
         """Attach a chaos injector to this executor and its current
@@ -173,7 +171,7 @@ class QueryExecutor:
         except Exception:
             self.restore(snap)
             raise
-        self.__fns = None
+        self._per_query = {}
 
     def swap_state(self, state: State,
                    groups: dict[str, list[str]] | None = None,
@@ -218,11 +216,11 @@ class QueryExecutor:
             self.workload = WorkloadExecutor(
                 self.dag, self.store.stats, self.infos, safety=self._safety,
                 use_pallas=self._use_pallas, max_retries=self._max_retries,
-                cap_planner=self._cap_planner, mode=self._workload_mode,
-                carry_caps=carry, fault_hook=self.fault_hook,
+                cap_planner=self._cap_planner, carry_caps=carry,
+                fault_hook=self.fault_hook,
             )
             self._results = None
-            self.__fns = None
+            self._per_query = {}
             if warm:
                 self.warmup()
         except Exception:
@@ -239,7 +237,7 @@ class QueryExecutor:
         their capacity classes precisely so this is NOT a refresh()."""
         self.store = store
         self._results = None
-        self.__fns = None
+        self._per_query = {}
 
     def warmup(self) -> None:
         """Compile every bucket body of the current program and cache
@@ -248,20 +246,20 @@ class QueryExecutor:
         roots = self.workload.warmup(self.tt, self.device_views)
         self._results = {name: E.to_numpy(rel) for name, rel in roots.items()}
 
-    @property
-    def _fns(self):
-        if self.__fns is None:
-            self.__fns = {}
-            for q in self.state.queries:
-                if q.name in self._oracle_names:
-                    continue
-                fn = E.build_executor(
-                    self.state.rewritings[q.name], self.store.stats,
-                    self.infos, safety=self._safety,
-                    use_pallas=self._use_pallas,
-                )
-                self.__fns[q.name] = (jax.jit(fn), fn.out_columns)
-        return self.__fns
+    def per_query_program(self, member: str) -> WorkloadExecutor:
+        """Tier 1's program for one non-oracle member: its rewriting
+        lowered alone (no shared subplans) through the same bucketed
+        lowering as the fused program, built on first use.  It carries
+        no fault hook: injected faults target the fused program."""
+        wl = self._per_query.get(member)
+        if wl is None:
+            wl = WorkloadExecutor(
+                build_dag({member: self.state.rewritings[member]}),
+                self.store.stats, self.infos, safety=self._safety,
+                use_pallas=self._use_pallas, max_retries=self._max_retries,
+                cap_planner=self._cap_planner, role="per_query")
+            self._per_query[member] = wl
+        return wl
 
     # ------------------------------------------------------------------
     def answer_workload(self) -> dict[str, np.ndarray]:
@@ -289,31 +287,22 @@ class QueryExecutor:
         return out
 
     # ------------------------------------------------------------------
-    def answer_per_query(self, name: str) -> np.ndarray:
-        """Legacy path: this member's rewriting compiled and run alone."""
-        fn, _cols = self._fns[name]
-        out = fn(self.tt, self.device_views)
-        if bool(out.overflow):
-            raise RuntimeError(
-                f"capacity overflow answering {name!r}; re-plan with a larger "
-                f"safety factor"
-            )
-        return E.to_numpy(out)
-
     def answer_group_per_query(self, original_name: str
                                ) -> set[tuple[int, ...]]:
-        """Union-group answer through the per-query unrolled path — the
-        serving ladder's first fallback when the fused program fails.
-        Each member compiles and runs alone (no shared subplans, raises
-        on overflow like the old engine); cartesian members fall back
-        to the oracle over the materialized extents as usual."""
+        """Union-group answer with each member run alone — the serving
+        ladder's first fallback when the fused program fails.  Each
+        member runs its own one-member program (`per_query_program`; no
+        shared subplans, overflow healed adaptively); cartesian members
+        fall back to the oracle over the materialized extents as
+        usual."""
         out: set[tuple[int, ...]] = set()
         for member in self.groups[original_name]:
             if member in self._oracle_names:
-                out |= {tuple(r) for r in self.answer(member).tolist()}
+                rows = self.answer(member)
             else:
-                out |= {tuple(r)
-                        for r in self.answer_per_query(member).tolist()}
+                rows = E.to_numpy(self.per_query_program(member).run(
+                    self.tt, self.device_views)[member])
+            out |= {tuple(r) for r in rows.tolist()}
         return out
 
     # ------------------------------------------------------------------

@@ -1,10 +1,12 @@
 """Shape-bucketed lowering of a `WorkloadDAG`: one scanned body per bucket.
 
-The unrolled fused executor (`compile_workload`) traces one closure per
-DAG node, so XLA graph size — and compile time — grows linearly with the
-workload.  At 1000+ members that is the wall.  This module applies the
-scan-over-layers idiom (levanter's `Stacked`, SNIPPETS.md Snippet 1) to
-query plans: nodes are grouped into *shape buckets* by
+This is the single-chip lowering of the plan IR: the serving program,
+the maintainer's delta program, device materialization and the serving
+ladder's per-query fallback all run through it.  Tracing one closure per
+DAG node would make XLA graph size — and compile time — grow linearly
+with the workload; this module instead applies the scan-over-layers
+idiom (levanter's `Stacked`, SNIPPETS.md Snippet 1) to query plans:
+nodes are grouped into *shape buckets* by
 
     (topological wave, operator kind, structural signature, capacity class)
 
@@ -54,9 +56,8 @@ from jax import lax
 from repro import trace
 from repro.query import cost as cost_mod
 from repro.query import engine as E
+from repro.query.cost import CAP_CEIL
 from repro.query.dag import WorkloadDAG
-
-CAP_CEIL = 1 << 22
 
 # the program span of one bucket dispatch, by operator kind
 _BUCKET_SPANS = {k: f"rdfviews.query.bucket.{k}"
@@ -461,9 +462,8 @@ def plan_capacities(dag: WorkloadDAG, stats, view_infos, *,
         elif node.kind == "join":
             lid, rid = node.child_ids
             pairs = node.spec
-            doms = [max(ests[lid].info.dcol(l), ests[rid].info.dcol(r))
-                    for l, r in pairs]
-            lead_k = max(range(len(doms)), key=lambda i: doms[i])
+            lead_k, demands[node.id] = cost_mod.join_lead(
+                pairs, ests[lid].info, ests[rid].info)
             lcol, rcol = pairs[lead_k]
             residual = tuple(p for k, p in enumerate(pairs)
                              if k != lead_k)
@@ -471,8 +471,6 @@ def plan_capacities(dag: WorkloadDAG, stats, view_infos, *,
             keep_right = tuple(i for i in range(dag.nodes[rid].width)
                                if i not in drop)
             join_specs[node.id] = (lcol, rcol, residual, keep_right)
-            demands[node.id] = max(
-                ests[lid].rows * ests[rid].rows / doms[lead_k], 1e-3)
             caps[node.id] = _cap(node, demands[node.id])
     return caps, scan_specs, join_specs, demands
 
@@ -485,8 +483,8 @@ class BucketedProgram:
 
     `execute(tt, views)` runs every bucket in wave order — one AOT
     compiled `lax.scan` dispatch per bucket — and returns
-    ({root name: PRel}, own_overflow np (n_nodes,)), the same contract
-    as the unrolled program plus host-side overflow attribution.
+    ({root name: PRel}, own_overflow np (n_nodes,)): the members'
+    results plus host-side overflow attribution.
 
     `promote(node_ids)` moves the offending nodes' buckets to the next
     capacity class; only those buckets' bodies (and consumers whose

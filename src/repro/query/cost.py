@@ -20,6 +20,9 @@ C_JOIN_PROBE = 1.0
 C_OUT = 1.0
 C_DEDUPE = 2.0
 
+# the largest buffer capacity class the engine plans or promotes to
+CAP_CEIL = 1 << 22
+
 
 def atom_cardinality(atom: Atom, stats: Statistics) -> float:
     p = atom.p.id if isinstance(atom.p, Const) else None
@@ -268,8 +271,23 @@ def estimate_dag(dag, stats: Statistics,
     return ests
 
 
+def join_lead(pairs, left: RelInfo, right: RelInfo) -> tuple[int, float]:
+    """The lead pair of an equi-join and its pre-residual expansion.
+
+    The engine joins on one column pair and checks the rest as residual
+    equalities; the lead is the pair whose larger distinct-count side
+    is largest (the most selective key).  Returns (index of the lead
+    pair in `pairs`, estimated rows the lead pair expands to before the
+    residuals filter them) — the row demand a join buffer is sized
+    from.  Column keys are names or output indexes, as `RelInfo` holds
+    them."""
+    doms = [max(left.dcol(l), right.dcol(r)) for l, r in pairs]
+    lead_k = max(range(len(doms)), key=lambda i: doms[i])
+    return lead_k, max(left.rows * right.rows / doms[lead_k], 1e-3)
+
+
 def capacity_for(rows_estimate: float, safety: float = 4.0, floor: int = 128,
-                 ceil: int = 1 << 22) -> int:
+                 ceil: int = CAP_CEIL) -> int:
     """Static buffer capacity for the JAX engine: next power of two above
     safety * estimate (the paper's statistics reused for shape planning)."""
     import math
@@ -279,7 +297,7 @@ def capacity_for(rows_estimate: float, safety: float = 4.0, floor: int = 128,
     return int(min(max(cap, floor), ceil))
 
 
-def promotion_chain(cap: int, ceil: int = 1 << 22,
+def promotion_chain(cap: int, ceil: int = CAP_CEIL,
                     max_steps: int = 64) -> list[int]:
     """The full capacity-class ladder from `cap` (exclusive) to the
     ceiling, as the adaptive driver would climb it one overflow at a
@@ -307,7 +325,7 @@ def promotion_chain(cap: int, ceil: int = 1 << 22,
         f"within {max_steps} steps")
 
 
-def promote_capacity(cap: int, ceil: int = 1 << 22) -> int:
+def promote_capacity(cap: int, ceil: int = CAP_CEIL) -> int:
     """Next capacity class above `cap` (classes are powers of two, so
     promotion doubles).  Returns `cap` unchanged once the ceiling is
     reached — callers treat a no-op promotion as 'cannot grow further'.

@@ -12,8 +12,11 @@ hash over the `data` mesh axis.  A rewriting becomes one SPMD program
     distributed hash join on jax.lax collectives,
   * co-partition elision: when both inputs are already partitioned by
     the join column (tracked statically through the plan), the
-    all_to_all is skipped — this is the main collective optimization
-    knob measured in EXPERIMENTS.md §Perf.
+    all_to_all is skipped — the main collective optimization knob.
+
+Per-device buffers are the global estimates divided by the device count
+times a skew factor, read from the `REPRO_QUERY_SKEW` environment
+variable (default 4.0; ROADMAP design debt 4).
 
 Buckets make the exchange static-shaped; overflow latches like the local
 engine.  The final relation stays sharded; `gather_result` collects it.
@@ -142,11 +145,10 @@ def build_distributed_executor(plan: Plan, stats, view_infos, mesh,
         if isinstance(node, EquiJoin):
             if not node.pairs:
                 raise NotImplementedError("cartesian products not supported distributed")
-            l_est = cost_mod.estimate_plan(node.left, stats, view_infos)
-            r_est = cost_mod.estimate_plan(node.right, stats, view_infos)
-            doms = [max(l_est.info.dcol(l), r_est.info.dcol(r))
-                    for l, r in node.pairs]
-            lead_k = max(range(len(doms)), key=lambda i: doms[i])
+            lead_k, lead_rows = cost_mod.join_lead(
+                node.pairs,
+                cost_mod.estimate_plan(node.left, stats, view_infos).info,
+                cost_mod.estimate_plan(node.right, stats, view_infos).info)
             lead_pair = node.pairs[lead_k]
             lf, lcols, linfo, lpart, _ = build(node.left)
             rf, rcols, rinfo, rpart, r_sorted_by = build(node.right,
@@ -156,7 +158,6 @@ def build_distributed_executor(plan: Plan, stats, view_infos, mesh,
                 (lcols.index(l), rcols.index(r))
                 for k, (l, r) in enumerate(node.pairs) if k != lead_k
             )
-            lead_rows = max(linfo.rows * rinfo.rows / doms[lead_k], 1e-3)
             drop = {r for _, r in node.pairs}
             keep_right = tuple(i for i, c in enumerate(rcols) if c not in drop)
             out_cols = lcols + tuple(c for c in rcols if c not in drop)

@@ -1,9 +1,11 @@
-"""JAX query engine: static-capacity padded relations.
+"""JAX query engine: static-capacity padded relations and their operators.
 
 XLA requires static shapes, so every relation is a `(capacity, width)`
 int32 buffer + a valid-row count + an overflow flag.  Capacities come
 from the same cardinality estimates the quality function uses
-(`cost.capacity_for`).  Invariants:
+(`cost.capacity_for`).  This module holds the operators and the static
+scan specs; plans are lowered onto them by `query/buckets.py` on one
+chip and by `query/distributed.py` across a mesh.  Invariants:
 
   * valid rows occupy a prefix `[0, n)`;
   * rows at `[n, capacity)` are scrubbed to -1 (no stale ids);
@@ -18,8 +20,7 @@ index in place: binary searches per left key, no scan buffer, no sort.
 """
 from __future__ import annotations
 
-import functools
-from typing import Callable, NamedTuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,8 +29,6 @@ from jax import lax
 
 from repro import trace
 from repro.core.queries import Const, Var
-from repro.query import cost as cost_mod
-from repro.query.plan import EquiJoin, Filter, Plan, Project, TTScan, ViewRef
 
 INVALID = jnp.int32(-1)
 SENTINEL_HI = jnp.int32(2**31 - 1)
@@ -296,7 +295,7 @@ def scan_pattern(index_data: jax.Array, prefix: tuple[tuple[int, int], ...],
 
 
 # ----------------------------------------------------------------------
-# plan compiler
+# static scan specs
 # ----------------------------------------------------------------------
 # all six index orders, as triple positions (s=0, p=1, o=2)
 INDEX_NAMES = ("spo", "pos", "osp", "pso", "ops", "sop")
@@ -384,112 +383,6 @@ def range_cardinality(atom, prefix, stats) -> float:
     o_val = atom.o.id if (2 in covered and isinstance(atom.o, Const)) else None
     return stats.atom_card(s_bound=0 in covered, p=p, o_bound=2 in covered,
                            o_val=o_val)
-
-
-def build_executor(plan: Plan, stats, view_infos: dict[int, "cost_mod.RelInfo"],
-                   safety: float = 4.0, use_pallas: bool = False,
-                   cap_override: Callable[[Plan, float], int] | None = None):
-    """Compile a plan into `fn(tt_indexes, views) -> PRel`.
-
-    `tt_indexes`: {"spo"|"pos"|"osp": (N,3) int32 device array}
-    `views`: {view_id: PRel}
-    `view_infos`: {view_id: cost.RelInfo} — extent cardinality + per-column
-    distincts (estimated from the view CQ, or measured after
-    materialization).  Buffer capacities are static, sized from the same
-    estimates the quality function uses; join lead columns are chosen to
-    minimize pre-residual expansion.
-    """
-
-    def cap_of(node: Plan, rows: float) -> int:
-        if cap_override is not None:
-            return cap_override(node, rows)
-        return cost_mod.capacity_for(rows, safety=safety)
-
-    def build(node: Plan, prefer_sorted: str | None = None
-              ) -> tuple[Callable, tuple[str, ...], "cost_mod.RelInfo", str | None]:
-        """returns (fn, cols, info, sorted_by)"""
-        est = cost_mod.estimate_plan(node, stats, view_infos)
-        if isinstance(node, TTScan):
-            idx_name, prefix, residual, takes, self_eq, sorted_by = \
-                atom_scan_spec(node.atom, prefer_sorted)
-            cap = cap_of(node, range_cardinality(node.atom, prefix, stats))
-            cols = node.columns()
-
-            def run(tt, views, _f=functools.partial(
-                    scan_pattern, prefix=prefix, residual=residual,
-                    takes=takes, self_eq=self_eq, cap=cap), _idx=idx_name):
-                return _f(tt[_idx])
-
-            return run, cols, est.info, sorted_by
-        if isinstance(node, ViewRef):
-            def run(tt, views, _vid=node.view_id):
-                return views[_vid]
-
-            return run, node.schema, est.info, None
-        if isinstance(node, Filter):
-            child_fn, cols, _, sorted_by = build(node.child, prefer_sorted)
-            ci = cols.index(node.col)
-
-            def run(tt, views, _fn=child_fn, _ci=ci, _v=node.value):
-                return filter_eq(_fn(tt, views), _ci, _v)
-
-            # compact() is stable: filtering preserves row order
-            return run, cols, est.info, sorted_by
-        if isinstance(node, EquiJoin):
-            if not node.pairs:
-                raise NotImplementedError(
-                    "cartesian products are not compiled to the device engine; "
-                    "disconnected rewritings stay on the oracle path"
-                )
-            # pick the lead pair from static estimates, then build children
-            # with the sort preference so scans can elide the join sort
-            l_est = cost_mod.estimate_plan(node.left, stats, view_infos)
-            r_est = cost_mod.estimate_plan(node.right, stats, view_infos)
-            doms = [
-                max(l_est.info.dcol(l), r_est.info.dcol(r))
-                for l, r in node.pairs
-            ]
-            lead_k = max(range(len(doms)), key=lambda i: doms[i])
-            lead_pair = node.pairs[lead_k]
-            lf, lcols, linfo, _ = build(node.left)
-            rf, rcols, rinfo, r_sorted_by = build(node.right, lead_pair[1])
-            lead = (lcols.index(lead_pair[0]), rcols.index(lead_pair[1]))
-            residual = tuple(
-                (lcols.index(l), rcols.index(r))
-                for k, (l, r) in enumerate(node.pairs) if k != lead_k
-            )
-            lead_rows = max(linfo.rows * rinfo.rows / doms[lead_k], 1e-3)
-            drop = {r for _, r in node.pairs}
-            keep_right = tuple(i for i, c in enumerate(rcols) if c not in drop)
-            out_cols = lcols + tuple(c for c in rcols if c not in drop)
-            cap = cap_of(node, lead_rows)
-            r_presorted = r_sorted_by == lead_pair[1]
-
-            def run(tt, views, _lf=lf, _rf=rf, _lead=lead, _res=residual,
-                    _keep=keep_right, _cap=cap, _rs=r_presorted):
-                return join(_lf(tt, views), _rf(tt, views), _lead[0], _lead[1],
-                            _res, _keep, _cap, use_pallas=use_pallas,
-                            right_sorted=_rs)
-
-            # join output follows left row-major order: sorted by nothing
-            # we track (expansion interleaves groups)
-            return run, out_cols, est.info, None
-        if isinstance(node, Project):
-            child_fn, cols, _, sorted_by = build(node.child, prefer_sorted)
-            idx = tuple(cols.index(c) for c in node.cols)
-            out_sorted = sorted_by if (not node.dedupe and sorted_by in node.cols) \
-                else (node.cols[0] if node.dedupe else None)
-
-            def run(tt, views, _fn=child_fn, _idx=idx, _d=node.dedupe):
-                return project(_fn(tt, views), _idx, _d)
-
-            return run, node.cols, est.info, out_sorted
-        raise TypeError(type(node))
-
-    fn, cols, info, _ = build(plan)
-    fn.out_columns = cols   # type: ignore[attr-defined]
-    fn.est_rows = info.rows  # type: ignore[attr-defined]
-    return fn
 
 
 def tt_device_indexes(store) -> dict[str, jax.Array]:

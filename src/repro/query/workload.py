@@ -1,31 +1,26 @@
-"""Fused workload execution: shape-bucketed (default) or unrolled.
+"""Fused workload execution: the adaptive driver over `BucketedProgram`.
 
-`compile_workload` lowers a `WorkloadDAG` (query/dag.py) into a single
-function evaluated in one device call: nodes run in topological order,
-each shared node computed once and its `PRel` buffer read by every
-consumer.  Static buffer capacities are planned DAG-wide from the cost
-model (`cost.estimate_dag` + `cost.capacity_for`).  This unrolled path
-traces one closure per node, so its compile time grows linearly with
-workload size — it remains as the A/B reference (`mode="unrolled"`).
+A `WorkloadDAG` (query/dag.py) is lowered by `query/buckets.py` into
+shape buckets: DAG nodes grouped by (wave, operator kind, structural
+signature, capacity class), each bucket executed as one `lax.scan` over
+stacked operands, compiled ahead-of-time through a process-global
+persistent cache.  Every shared node is computed once and its buffer
+read by every consumer, and compile time scales with the number of
+distinct shapes, not the number of queries — near-flat from 22 to 1000+
+workload members (benchmarks/bench_compile_scale.py).  Static buffer
+capacities are planned DAG-wide from the cost model
+(`cost.estimate_dag` + `cost.capacity_for`).
 
-The default lowering is *shape-bucketed* (`query/buckets.py`,
-`mode="bucketed"`): DAG nodes are grouped by (wave, operator kind,
-structural signature, capacity class) and each bucket executes as one
-`lax.scan` over stacked operands, compiled ahead-of-time through a
-process-global persistent cache.  Compile time scales with the number
-of distinct shapes, not the number of queries — near-flat from 22 to
-1000+ workload members (benchmarks/bench_compile_scale.py).
-
-`WorkloadExecutor` wraps either program in an adaptive driver: alongside
-the root results it observes each node's *own* overflow flag (latched
-overflow minus anything inherited from children), so when a capacity
-proves too small the driver knows exactly which buffer to grow.  In
-bucketed mode an overflow promotes only the offending node's *bucket*
-to the next capacity class — the next execute recompiles that bucket's
-body (and any consumer whose operand shape changed); every untouched
-body is a cache hit.  Capacities learned this way can be carried into a
-successor executor (`learned_caps()` / `carry_caps=`), so a hot-swapped
-program does not re-learn overflows the previous one already healed.
+`WorkloadExecutor` drives that program adaptively: alongside the root
+results it observes each node's *own* overflow flag (latched overflow
+minus anything inherited from children), so when a capacity proves too
+small the driver knows exactly which buffer to grow.  An overflow
+promotes only the offending node's *bucket* to the next capacity class
+— the next execute recompiles that bucket's body (and any consumer
+whose operand shape changed); every untouched body is a cache hit.
+Capacities learned this way can be carried into a successor executor
+(`learned_caps()` / `carry_caps=`), so a hot-swapped program does not
+re-learn overflows the previous one already healed.
 
 The fused path compiles scans without consumer-specific sort
 preferences (a shared scan can't commit to one consumer's join order),
@@ -35,138 +30,24 @@ sort it gives up.
 """
 from __future__ import annotations
 
-import functools
 from typing import Callable
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.query import cost as cost_mod
 from repro.query import engine as E
 from repro.query.buckets import BucketedProgram, compile_cache
+from repro.query.cost import CAP_CEIL
 from repro.query.dag import WorkloadDAG
-
-CAP_CEIL = 1 << 22
-
-
-def compile_workload(dag: WorkloadDAG, stats, view_infos,
-                     safety: float = 4.0, use_pallas: bool = False,
-                     caps: list[int] | None = None,
-                     cap_planner: Callable[[object, float], int] | None = None,
-                     ests=None):
-    """Lower the DAG into `fn(tt, views) -> (roots, own_overflow)`.
-
-    roots: {member name: PRel}; own_overflow: (n_nodes,) bool vector of
-    node-local overflows.  `caps` pins every node's buffer capacity
-    (adaptive recompiles); when None, capacities are planned from the
-    DAG-wide estimates (`cap_planner(node, est_rows)` overrides the
-    default `capacity_for`, mirroring `build_executor`'s cap_override).
-    The planned capacities are returned on `fn.caps`.  `ests` accepts
-    precomputed `cost.estimate_dag` output (estimates don't depend on
-    capacities, so adaptive recompiles can reuse them).
-    """
-    if ests is None:
-        ests = cost_mod.estimate_dag(dag, stats, view_infos)
-    plan_caps = caps is None
-    if plan_caps:
-        caps = [0] * len(dag.nodes)
-
-    def _cap(node, rows: float) -> int:
-        if cap_planner is not None:
-            return int(cap_planner(node.plan, rows))
-        return cost_mod.capacity_for(rows, safety=safety)
-
-    steps: list[tuple[Callable, tuple[int, ...], str]] = []
-    for node in dag.nodes:
-        if node.kind == "scan":
-            idx_name, prefix, residual, takes, self_eq, _sorted = \
-                E.atom_scan_spec(node.spec)
-            if plan_caps:
-                caps[node.id] = _cap(
-                    node, E.range_cardinality(node.spec, prefix, stats))
-
-            def step(tt, views, res, _f=functools.partial(
-                    E.scan_pattern, prefix=prefix, residual=residual,
-                    takes=takes, self_eq=self_eq, cap=caps[node.id]),
-                    _idx=idx_name):
-                return _f(tt[_idx])
-
-        elif node.kind == "view":
-            def step(tt, views, res, _vid=node.spec):
-                return views[_vid]
-
-        elif node.kind == "filter":
-            ci, value = node.spec
-
-            def step(tt, views, res, _c=node.child_ids[0], _ci=ci, _v=value):
-                return E.filter_eq(res[_c], _ci, _v)
-
-        elif node.kind == "join":
-            lid, rid = node.child_ids
-            pairs = node.spec
-            doms = [max(ests[lid].info.dcol(l), ests[rid].info.dcol(r))
-                    for l, r in pairs]
-            lead_k = max(range(len(doms)), key=lambda i: doms[i])
-            lcol, rcol = pairs[lead_k]
-            residual = tuple(p for k, p in enumerate(pairs) if k != lead_k)
-            drop = {r for _, r in pairs}
-            keep_right = tuple(i for i in range(dag.nodes[rid].width)
-                               if i not in drop)
-            if plan_caps:
-                lead_rows = max(
-                    ests[lid].rows * ests[rid].rows / doms[lead_k], 1e-3)
-                caps[node.id] = _cap(node, lead_rows)
-
-            def step(tt, views, res, _l=lid, _r=rid, _lc=lcol, _rc=rcol,
-                     _res=residual, _keep=keep_right, _cap=caps[node.id]):
-                return E.join(res[_l], res[_r], _lc, _rc, _res, _keep, _cap,
-                              use_pallas=use_pallas)
-
-        elif node.kind == "project":
-            idxs, dedupe = node.spec
-
-            def step(tt, views, res, _c=node.child_ids[0], _idx=idxs,
-                     _d=dedupe):
-                return E.project(res[_c], _idx, _d)
-
-        else:
-            raise TypeError(node.kind)
-        steps.append((step, node.child_ids, node.kind))
-
-    roots = dict(dag.roots)
-
-    def fn(tt, views):
-        res: list[E.PRel] = []
-        own: list[jax.Array] = []
-        for run, child_ids, kind in steps:
-            rel = run(tt, views, res)
-            if kind == "view":
-                # view buffers are packed at exact capacity by the
-                # materializer; nothing here for the driver to grow
-                own.append(jnp.asarray(False))
-            else:
-                inherited = jnp.asarray(False)
-                for c in child_ids:
-                    inherited = inherited | res[c].overflow
-                own.append(rel.overflow & ~inherited)
-            res.append(rel)
-        ovf = jnp.stack(own) if own else jnp.zeros((0,), dtype=bool)
-        return {name: res[nid] for name, nid in roots.items()}, ovf
-
-    fn.caps = caps  # type: ignore[attr-defined]
-    return fn
 
 
 class WorkloadExecutor:
     """Adaptive driver around the fused workload program.
 
-    `run` executes the whole workload; on capacity overflow it grows the
-    offending buffers (bucketed mode: promotes the offending *buckets*
-    to the next capacity class; unrolled mode: doubles the node's
-    buffer), recompiles what changed, and retries — up to `max_retries`
-    recompiles, after which (or once a buffer hits the capacity ceiling)
-    it raises.
+    `run` executes the whole workload; on capacity overflow it promotes
+    the offending *buckets* to the next capacity class, recompiles what
+    changed, and retries — up to `max_retries` recompiles, after which
+    (or once a buffer hits the capacity ceiling) it raises.
 
     `carry_caps` seeds planning with capacities a previous executor
     learned (`learned_caps()`), keyed by DAG content key, so a rebuilt
@@ -178,11 +59,8 @@ class WorkloadExecutor:
                  safety: float = 4.0, use_pallas: bool = False,
                  max_retries: int = 12,
                  cap_planner: Callable[[object, float], int] | None = None,
-                 mode: str = "bucketed",
                  carry_caps: dict | None = None,
                  fault_hook=None, role: str = "workload"):
-        if mode not in ("bucketed", "unrolled"):
-            raise ValueError(f"unknown workload mode {mode!r}")
         # fault_hook: duck-typed chaos injector (`.fire(site)` raising an
         # injected fault when armed); None in production.  Sites fired
         # here: "compile" on program (re)construction, "device_call" and
@@ -197,7 +75,6 @@ class WorkloadExecutor:
         self.use_pallas = use_pallas
         self.max_retries = max_retries
         self.cap_planner = cap_planner
-        self.mode = mode
         self.carry_caps = dict(carry_caps or {})
         self.caps: list[int] | None = None
         # telemetry
@@ -205,7 +82,6 @@ class WorkloadExecutor:
         self.runs = 0
         self.recompiles = 0
         self.cap_history: dict[int, list[int]] = {}
-        self._jit = None
         self._prog: BucketedProgram | None = None
         self._ests = None
 
@@ -221,18 +97,6 @@ class WorkloadExecutor:
     def _fire(self, site: str) -> None:
         if self.fault_hook is not None:
             self.fault_hook.fire(site)
-
-    def _compile(self) -> None:
-        """Unrolled mode: (re)trace the whole program."""
-        self._fire("compile")
-        fn = compile_workload(self.dag, self.stats, self.view_infos,
-                              safety=self.safety, use_pallas=self.use_pallas,
-                              caps=self.caps, cap_planner=self.cap_planner,
-                              ests=self._ensure_ests())
-        self.caps = fn.caps
-        fn.__name__ = fn.__qualname__ = f"{self.role}_unrolled"
-        self._jit = jax.jit(fn)
-        self.compiles += 1
 
     def _program(self, tt=None, views=None) -> BucketedProgram:
         """The bucketed program, lowered on first use against the shapes
@@ -256,11 +120,6 @@ class WorkloadExecutor:
         """Answer every workload member; returns {name: PRel}."""
         self._fire("device_call")
         self._fire("capacity_overflow")
-        if self.mode == "bucketed":
-            return self._run_bucketed(tt, views)
-        return self._run_unrolled(tt, views)
-
-    def _run_bucketed(self, tt, views) -> dict[str, E.PRel]:
         prog = self._program(tt, views)
         attempt = 0
         while True:
@@ -288,54 +147,18 @@ class WorkloadExecutor:
             self.recompiles += 1
             attempt += 1
 
-    def _run_unrolled(self, tt, views) -> dict[str, E.PRel]:
-        if self._jit is None:
-            self._compile()
-        attempt = 0
-        while True:
-            roots, own = self._jit(tt, views)
-            self.runs += 1
-            own_np = np.asarray(own)
-            if not own_np.any():
-                return roots
-            offending = np.nonzero(own_np)[0].tolist()
-            if attempt >= self.max_retries:
-                raise RuntimeError(
-                    f"capacity overflow persists after {attempt} adaptive "
-                    f"recompiles (nodes {offending}); estimates are "
-                    f"pathologically low — raise max_retries or safety"
-                )
-            grew = False
-            for nid in offending:
-                cur = self.caps[nid]
-                new = min(max(cur * 2, 2), CAP_CEIL)
-                if new > cur:
-                    self.caps[nid] = new
-                    self.cap_history.setdefault(nid, [cur]).append(new)
-                    grew = True
-            if not grew:
-                raise RuntimeError(
-                    f"capacity ceiling ({CAP_CEIL}) reached on nodes "
-                    f"{offending}; result exceeds the engine's maximum "
-                    f"buffer size"
-                )
-            self._compile()
-            self.recompiles += 1
-            attempt += 1
-
     # ------------------------------------------------------------------
     # static verification
     # ------------------------------------------------------------------
     def analyze(self, n_tt: int | None = None, view_caps=None):
         """Run the static analyzers (IR verifier, capacity analysis,
-        jaxpr lint) over this executor's DAG and — in bucketed mode —
-        its compiled-shape program, without executing anything.  Returns
-        an `repro.analysis.AnalysisReport`."""
+        jaxpr lint) over this executor's DAG and its compiled-shape
+        program, without executing anything.  Returns an
+        `repro.analysis.AnalysisReport`."""
         from repro import analysis
 
-        program = self._program() if self.mode == "bucketed" else None
         return analysis.analyze_workload(
-            self.dag, self.stats, self.view_infos, program=program,
+            self.dag, self.stats, self.view_infos, program=self._program(),
             n_tt=n_tt, view_caps=view_caps)
 
     # ------------------------------------------------------------------
@@ -366,10 +189,9 @@ class WorkloadExecutor:
         t = dict(self.dag.stats())
         t.update(compiles=self.compiles, runs=self.runs,
                  recompiles=self.recompiles,
-                 grown_nodes=sorted(self.cap_history),
-                 mode=self.mode)
-        # bucket/compile-cache telemetry (zeros on the unrolled path so
-        # consumers can rely on the keys being present)
+                 grown_nodes=sorted(self.cap_history))
+        # bucket/compile-cache telemetry (zeros until the program is
+        # built, so consumers can rely on the keys being present)
         t.update(buckets=0, bucket_signatures=0, bucket_compiles=0,
                  bucket_cache_hits=0, bucket_cache_misses=0,
                  bucket_compile_seconds=0.0,

@@ -14,7 +14,8 @@ matching `QueryExecutor.answer_group`.
 Serving is FAULT TOLERANT through a degradation ladder (docs/serving.md):
 
   tier 0  fused device program (fast path; circuit-broken on failure)
-  tier 1  per-query unrolled jitted path (no shared subplans)
+  tier 1  each member's rewriting run alone through the same bucketed
+          lowering (no shared subplans; overflow healed adaptively)
   tier 2  host reference engine over the raw triple table (exact,
           independent of view extents and device state)
   tier 3  last-known-good cached answers, explicitly flagged stale
@@ -370,7 +371,7 @@ class QueryServer:
                         self.stats.fused_failures += 1
                         self._note_fault("fused", exc)
 
-        # ---- tier 1: per-query unrolled path ----------------------
+        # ---- tier 1: each member run alone -------------------------
         if extents_ok:
             try:
                 if self.chaos is not None:

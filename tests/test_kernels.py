@@ -93,14 +93,17 @@ def test_engine_with_pallas_join_matches_oracle():
     LUBM workload identically to the oracle."""
     from repro.query import engine as E
     from repro.query import ref_engine as R
+    from repro.query.dag import build_dag
     from repro.query.plan import plan_for_cq
+    from repro.query.workload import WorkloadExecutor
     from repro.rdf.generator import generate, lubm_workload
 
     uni = generate(n_universities=1, seed=0)
     tt = E.tt_device_indexes(uni.store)
     for q in lubm_workload(uni.dictionary):
-        fn = E.build_executor(plan_for_cq(q), uni.store.stats, {}, use_pallas=True)
-        out = fn(tt, {})
+        wl = WorkloadExecutor(build_dag({q.name: plan_for_cq(q)}),
+                              uni.store.stats, {}, use_pallas=True)
+        out = wl.run(tt, {})[q.name]
         assert not bool(out.overflow)
         got = {tuple(r) for r in E.to_numpy(out).tolist()}
         want = R.evaluate_cq(q, uni.store).as_set()

@@ -2,13 +2,15 @@
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.queries import CQ, Atom, Const, Var
 from repro.query import engine as E
 from repro.query import ref_engine as R
+from repro.query.buckets import BucketedProgram
+from repro.query.dag import build_dag
 from repro.query.plan import EquiJoin, Filter, Project, TTScan, ViewRef, plan_for_cq
+from repro.query.workload import WorkloadExecutor
 from repro.rdf.generator import generate, lubm_workload
 from repro.rdf.triples import TripleStore
 
@@ -29,17 +31,20 @@ def _measured_info(rel):
 
 
 def _run_plan(plan, store, views_np=None, view_cards=None, use_pallas=False):
+    """`plan` as a one-member bucketed program over `store` and the
+    measured extents of `views_np`."""
     views_np = views_np or {}
     view_cards = view_cards or {vid: _measured_info(rel) for vid, rel in views_np.items()}
-    fn = E.build_executor(plan, store.stats, view_cards, use_pallas=use_pallas)
+    wl = WorkloadExecutor(build_dag({"q": plan}), store.stats, view_cards,
+                          use_pallas=use_pallas)
     tt = E.tt_device_indexes(store)
     views = {
         vid: E.make_prel(rel.rows, cap=max(128, 1 << int(np.ceil(np.log2(max(len(rel.rows), 1) + 1)))))
         for vid, rel in views_np.items()
     }
-    out = jax.jit(lambda tt, views: fn(tt, views))(tt, views)
+    out = wl.run(tt, views)["q"]
     assert not bool(out.overflow), "capacity overflow in test plan"
-    return E.to_numpy(out), fn.out_columns
+    return E.to_numpy(out), plan.columns()
 
 
 def _oracle(plan, store, views_np=None):
@@ -136,9 +141,11 @@ def test_overflow_flag():
                   np.arange(600, dtype=np.int32)], axis=1)
     ts = TripleStore(t)
     plan = TTScan(Atom(Var("x"), Const(1), Var("y")))
-    fn = E.build_executor(plan, ts.stats, {}, cap_override=lambda n, r: 128)
-    out = fn(E.tt_device_indexes(ts), {})
-    assert bool(out.overflow)
+    prog = BucketedProgram(build_dag({"q": plan}), ts.stats, {},
+                           cap_planner=lambda n, r: 128)
+    roots, own = prog.execute(E.tt_device_indexes(ts), {})
+    out = roots["q"]
+    assert bool(out.overflow) and own.any()
     assert int(out.n) == 128
 
 
@@ -264,9 +271,6 @@ def test_probe_join_overflow_sets_flag_and_promotion_heals():
     # the bucketed program: a join planned too small for its matches
     # lowers to a probe, overflows, and the adaptive driver promotes
     # the probe bucket until the answer is whole
-    from repro.query.dag import build_dag
-    from repro.query.workload import WorkloadExecutor
-
     plan = EquiJoin(ViewRef(0, ("x", "z")), TTScan(atom), (("x", "x"),))
     ext = R.Relation(left, ("x", "z"))
     wl = WorkloadExecutor(build_dag({"q": plan}), store.stats,

@@ -81,7 +81,7 @@ def test_maintenance_incremental_equals_recompute(uni):
 
 
 def test_executor_jax_matches_oracle_per_member(uni, report):
-    for name in report.executor._fns:
+    for name in report.executor.dag.roots:
         got = {tuple(r) for r in report.executor.answer(name).tolist()}
         want = report.executor.answer_direct(name)
         assert got == want, name

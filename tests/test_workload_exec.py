@@ -165,7 +165,6 @@ def test_overflow_promotes_only_offending_bucket(uni):
         assert got == R.evaluate_cq(q, uni.store).as_set(), q.name
     assert wl.recompiles >= 1
     t = wl.telemetry()
-    assert t["mode"] == "bucketed"
     promoted = [b for b in wl._prog.buckets if b.promotions > 0]
     assert len(promoted) == 1  # exactly one bucket grew...
     assert promoted[0].kind == "scan" and len(promoted[0].node_ids) == 3
@@ -182,20 +181,16 @@ def test_overflow_promotes_only_offending_bucket(uni):
 
 
 def test_bucketed_matches_unrolled(uni):
-    """A/B: the bucketed lowering answers member-for-member identically
-    to the unrolled reference program."""
+    """The bucketed lowering answers every member of the course-scan
+    workload exactly as the reference engine does."""
     qs, _ = _course_scan_workload(uni)
     dag = build_dag({q.name: plan_for_cq(q) for q in qs})
     tt = E.tt_device_indexes(uni.store)
-    rb = WorkloadExecutor(dag, uni.store.stats, {},
-                          mode="bucketed").run(tt, {})
-    ru = WorkloadExecutor(dag, uni.store.stats, {},
-                          mode="unrolled").run(tt, {})
-    assert set(rb) == set(ru)
-    for name in rb:
-        got_b = {tuple(r) for r in E.to_numpy(rb[name]).tolist()}
-        got_u = {tuple(r) for r in E.to_numpy(ru[name]).tolist()}
-        assert got_b == got_u, name
+    roots = WorkloadExecutor(dag, uni.store.stats, {}).run(tt, {})
+    assert set(roots) == {q.name for q in qs}
+    for q in qs:
+        got = {tuple(r) for r in E.to_numpy(roots[q.name]).tolist()}
+        assert got == R.evaluate_cq(q, uni.store).as_set(), q.name
 
 
 def test_learned_caps_carry_to_successor(uni, members, baseline_dag):
@@ -260,7 +255,6 @@ def test_bucket_telemetry_reaches_server_stats(uni, report):
     srv = QueryServer(report.executor)
     srv.answer_batch([q.name for q in lubm_workload(uni.dictionary)])
     t = report.executor.telemetry()
-    assert t["mode"] == "bucketed"
     assert t["buckets"] >= 1
     assert t["bucket_compiles"] + t["bucket_cache_hits"] >= t["buckets"]
     assert t["compile_cache"]["entries"] >= 1
@@ -281,7 +275,7 @@ def test_executor_single_device_call_for_workload(uni, report):
     ex.answer_workload()
     first_runs = ex.workload.runs
     # every member answer comes from the same cached fused run
-    for name in ex._fns:
+    for name in ex.dag.roots:
         got = {tuple(r) for r in ex.answer(name).tolist()}
         assert got == ex.answer_direct(name), name
     assert ex.workload.runs == first_runs
@@ -289,10 +283,37 @@ def test_executor_single_device_call_for_workload(uni, report):
 
 
 def test_legacy_per_query_path_matches(uni, report):
+    """Tier 1 (each member run alone) answers every group exactly."""
     ex = report.executor
-    for name in list(ex._fns)[:4]:
-        got = {tuple(r) for r in ex.answer_per_query(name).tolist()}
-        assert got == ex.answer_direct(name), name
+    for name in list(ex.groups)[:4]:
+        assert ex.answer_group_per_query(name) == \
+            ex.answer_group_direct(name), name
+
+
+def test_per_query_tier_heals_overflow(uni):
+    """A tier-1 member whose planned capacity overflows on its first run
+    is promoted by the adaptive driver and answers exactly."""
+    from repro.core.executor import QueryExecutor
+    from repro.core.state import State
+
+    qs, takes = _course_scan_workload(uni)
+    state = State(views={}, queries=tuple(qs),
+                  rewritings={q.name: plan_for_cq(q) for q in qs})
+
+    def planner(plan, rows):
+        if isinstance(plan, TTScan) and plan.atom.p == takes:
+            return 2  # every course has more than 2 takers
+        return 512
+
+    ex = QueryExecutor(uni.store, state, cap_planner=planner,
+                       max_retries=16)
+    for q in qs:
+        assert ex.answer_group_per_query(q.name) == \
+            ex.answer_group_direct(q.name), q.name
+    healed = [q.name for q in qs
+              if ex.per_query_program(q.name).recompiles >= 1]
+    assert len(healed) == 3  # the three takesCourse members
+    assert ex.workload.runs == 0  # tier 1 never ran the fused program
 
 
 # ----------------------------------------------------------------------
